@@ -217,6 +217,9 @@ def main(argv=None) -> int:
                     help="skip the BENCH_history.jsonl append")
     args = ap.parse_args(argv)
     sha = args.sha if args.sha is not None else os.environ.get("GIT_SHA", "")
+    from repro.launch.compile_cache import place_compile_cache
+
+    place_compile_cache()
 
     if args.check_regression:
         return check_regression(pathlib.Path(args.baseline), args.tolerance)

@@ -28,9 +28,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.api import compile_snn, init_snn
-from repro.compat import AxisType, make_mesh
 from repro.configs.saocds_amc import CONFIG as SNN_CONFIG
 from repro.data.pipeline import sigma_delta_encode_np
 from repro.data.radioml import generate_batch
@@ -41,7 +41,7 @@ def main():
     cfg = SNN_CONFIG
     program = compile_snn(cfg)
     params = init_snn(jax.random.PRNGKey(0), cfg)
-    mesh = make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
 
     # heterogeneous stages share one fixed-width buffer — the software
     # analogue of the accelerator's fixed inter-layer stream width.

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 __all__ = ["spmd_pipeline", "stack_stage_params"]
 
 
@@ -68,7 +66,7 @@ def spmd_pipeline(
     ticks = n_micro + n_stages - 1
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P() if collect == "psum" else P(stage_axis),

@@ -322,10 +322,10 @@ def serve_mesh(n_devices: Optional[int] = None) -> Mesh:
     batch.  A 1-device mesh is valid and keeps the shard_map code path
     identical from laptop to pod.
     """
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
 
     n = n_devices if n_devices is not None else jax.local_device_count()
-    return make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def serve_batch_pspec(mesh: Mesh) -> P:
@@ -341,11 +341,9 @@ def shard_serve_fn(fn, mesh: Mesh):
     micro-batcher guarantees every bucket size is a multiple of the data
     axis, so the split is always even.  Callers still jit the result.
     """
-    from repro.compat import shard_map
-
     spec = serve_batch_pspec(mesh)
-    return shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 def spec_report(spec_tree: Any, shape_tree: Any) -> str:

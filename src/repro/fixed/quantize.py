@@ -35,6 +35,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.train.lsq import lsq_fake_quant, make_serving_quant_fn
@@ -187,6 +188,12 @@ class FixedQuantFn:
             s = float(np.float32(self._flat[self._idx % len(self._flat)]))
             s = float(np.float32(max(s, _STEP_FLOOR)))
             self._idx += 1
+        if not isinstance(w, jax.core.Tracer):
+            # concrete weights quantize on the host, exactly as the golden
+            # does: a TPU divides inexactly, and one weight rounded to the
+            # other side of a half step gives the chip a different code
+            return quantize_codes(w, s, self.bits).astype(np.float32) \
+                * np.float32(s)
         qmax = 2 ** (self.bits - 1) - 1
         qmin = -(2 ** (self.bits - 1))
         return jnp.clip(jnp.round(w / s), qmin, qmax) * jnp.float32(s)

@@ -25,11 +25,14 @@ TPU v5e core; BOI can be raised to 512 for wider layers.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["goap_conv_block_sparse"]
 
@@ -45,7 +48,8 @@ def _kernel(cols_ref, blocks_ref, x_ref, out_ref):
     # padded no-op tiles carry zero data (and point at k-tile 0): they
     # contribute nothing — the static-schedule trick, no conditionals.
     out_ref[...] += jnp.dot(
-        blocks_ref[0, 0], x_ref[...], preferred_element_type=out_ref.dtype
+        blocks_ref[0, 0], x_ref[...], precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=out_ref.dtype,
     )
 
 
@@ -60,7 +64,7 @@ def goap_conv_block_sparse(
     block_oc: int,
     block_k: int,
     block_oi: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Returns currents (n_oc_tiles * BO, OI_padded) = block-sparse W' @ X'."""
     n_oc_tiles, max_tiles, bo, bk = blocks.shape
@@ -91,6 +95,6 @@ def goap_conv_block_sparse(
         out_shape=jax.ShapeDtypeStruct(
             (n_oc_tiles * block_oc, oi_padded), blocks.dtype
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="goap_conv_block_sparse",
     )(block_cols, blocks, x)

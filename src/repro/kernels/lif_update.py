@@ -7,39 +7,34 @@ the membrane row in **VMEM scratch across the whole T loop** — HBM sees one
 read of the currents per timestep and one write of the spikes, the state
 never round-trips (vs. 3 HBM touches/step for the naive unfused chain).
 
-Grid: (neuron-tiles, T) with T the minor (sequential) dimension; the state
-scratch carries across T iterations of the same neuron tile.
+Grid: (neuron-tiles,); each step carries one (T, 128) tile of currents
+through all T timesteps, so the state never leaves the core.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["lif_update_fused"]
 
 
 def _kernel(cur_ref, v0_ref, alpha_ref, theta_ref, vth_ref,
-            spikes_ref, vfin_ref, v_scratch):
-    t = pl.program_id(1)
-    n_t = pl.num_programs(1)
-
-    @pl.when(t == 0)
-    def _load():
-        v_scratch[...] = v0_ref[...]
-
-    v = v_scratch[...] * alpha_ref[...] + cur_ref[0]
-    s = (v > vth_ref[...]).astype(v.dtype)
-    v = v - theta_ref[...] * s
-    spikes_ref[0] = s
-    v_scratch[...] = v
-
-    @pl.when(t == n_t - 1)
-    def _store():
-        vfin_ref[...] = v_scratch[...]
+            spikes_ref, vfin_ref):
+    """One neuron tile over all T: the state stays in vregs/VMEM."""
+    v = v0_ref[...]
+    alpha, theta, v_th = alpha_ref[...], theta_ref[...], vth_ref[...]
+    for t in range(cur_ref.shape[0]):
+        v = v * alpha + cur_ref[t:t + 1, :]
+        s = (v > v_th).astype(v.dtype)
+        v = v - theta * s
+        spikes_ref[t:t + 1, :] = s
+    vfin_ref[...] = v
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -51,37 +46,29 @@ def lif_update_fused(
     v_th: jax.Array,      # (N,) threshold
     *,
     block_n: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (spikes (T, N), v_final (N,)). One HBM pass over currents."""
     t_steps, n = currents.shape
     pad_n = (-n) % block_n
     cur = jnp.pad(currents, ((0, 0), (0, pad_n)))
-    pad1 = lambda a: jnp.pad(a, (0, pad_n))
-    v0p, al, th, vt = pad1(v0), pad1(alpha), pad1(theta), pad1(v_th)
+    row = lambda a: jnp.pad(a, (0, pad_n))[None, :]  # (1, N_padded)
+    v0p, al, th, vt = row(v0), row(alpha), row(theta), row(v_th)
     # avoid spurious spikes in the padded region (v_th would be 0 there)
-    vt = vt.at[n:].set(jnp.inf) if pad_n else vt
+    vt = vt.at[:, n:].set(jnp.inf) if pad_n else vt
 
-    n_tiles = cur.shape[1] // block_n
-    grid = (n_tiles, t_steps)
-    vec = lambda: pl.BlockSpec((block_n,), lambda i, t: (i,))
+    grid = (cur.shape[1] // block_n,)
+    tile = lambda rows: pl.BlockSpec((rows, block_n), lambda i: (0, i))
     spikes, v_fin = pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_n), lambda i, t: (t, i)),
-            vec(), vec(), vec(), vec(),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda i, t: (t, i)),
-            vec(),
-        ],
+        in_specs=[tile(t_steps), tile(1), tile(1), tile(1), tile(1)],
+        out_specs=[tile(t_steps), tile(1)],
         out_shape=[
             jax.ShapeDtypeStruct(cur.shape, currents.dtype),
-            jax.ShapeDtypeStruct((cur.shape[1],), currents.dtype),
+            jax.ShapeDtypeStruct((1, cur.shape[1]), currents.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((block_n,), currents.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="lif_update_fused",
     )(cur, v0p, al, th, vt)
-    return spikes[:, :n], v_fin[:n]
+    return spikes[:, :n], v_fin[0, :n]

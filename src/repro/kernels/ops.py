@@ -4,8 +4,8 @@ These are the entry points the model/serving layers use: they accept the
 framework-level objects (``BlockSparseKernel``, dense IFMs, ``LIFParams``)
 and handle the padding/layout plumbing around the raw kernels.
 
-``interpret`` defaults to True because this container is CPU-only (TPU v5e
-is the compile target); on real TPU hardware pass ``interpret=False``.
+The kernels compile on a TPU and run in interpret mode on the CPU
+(:mod:`repro.kernels.platform`).
 """
 from __future__ import annotations
 
@@ -30,7 +30,6 @@ def goap_conv_op(
     bs: BlockSparseKernel,
     *,
     block_oi: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """Sparse conv currents (OC, OI) via the block-sparse GOAP kernel."""
     ic, wi = ifm.shape
@@ -48,7 +47,6 @@ def goap_conv_op(
         block_oc=bs.block_oc,
         block_k=bs.block_k,
         block_oi=block_oi,
-        interpret=interpret,
     )
     return out[: bs.oc, :oi]
 
@@ -56,12 +54,10 @@ def goap_conv_op(
 def wm_fc_op(
     spikes: jax.Array,   # (B, IN) or (IN,) binary
     weights: jax.Array,  # (IN, OUT) masked weights
-    *,
-    interpret: bool = True,
 ) -> jax.Array:
     squeeze = spikes.ndim == 1
     s = spikes[None] if squeeze else spikes
-    out = wm_fc_matmul(s, weights, interpret=interpret)
+    out = wm_fc_matmul(s, weights)
     return out[0] if squeeze else out
 
 
@@ -69,8 +65,6 @@ def lif_op(
     currents: jax.Array,  # (T, ...) input currents
     params: LIFParams,
     v0: jax.Array | None = None,
-    *,
-    interpret: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused LIF over time for arbitrary neuron shape; returns (spikes, v_fin)."""
     t = currents.shape[0]
@@ -80,7 +74,5 @@ def lif_op(
     full = lambda p: jnp.broadcast_to(p, neuron_shape).reshape(-1)
     v0f = jnp.zeros((n,), cur.dtype) if v0 is None else v0.reshape(-1)
     spikes, v_fin = lif_update_fused(
-        cur, v0f, full(params.alpha), full(params.theta), full(params.v_th),
-        interpret=interpret,
-    )
+        cur, v0f, full(params.alpha), full(params.theta), full(params.v_th))
     return spikes.reshape((t,) + neuron_shape), v_fin.reshape(neuron_shape)

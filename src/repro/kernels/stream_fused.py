@@ -20,16 +20,27 @@ still launches every layer's XLA ops per scan step, and the per-layer
 ``pallas`` backend, which costs T x L kernel launches per sample.
 
 The conv inside the kernel uses the GOAP shift-buffer identity: the
-padded frame is expanded to X'(KW*IC, W) (rows ordered ci-major so the
-expansion is a 2-D concatenation, Mosaic-friendly) and the layer current
-is one ``(OC, KW*IC) @ (KW*IC, W)`` MXU matmul.  The gated-accumulation
-counter of the ``stream`` backend is recovered exactly (integer-valued
-f32) as ``counts · row_sums(X')`` where ``counts[r]`` is the number of
-non-zero weights mapping to shift-buffer row ``r`` — summing enable maps
-per non-zero weight and summing row occupancies are the same double sum.
+frame is expanded to X'(KW*IC, W) (rows ordered ci-major) and the layer
+current is one ``(OC, KW*IC) @ (KW*IC, W)`` MXU matmul.  The gated-
+accumulation counter of the ``stream`` backend is recovered exactly
+(integer-valued f32) as ``counts · row_sums(X')`` where ``counts[r]`` is
+the number of non-zero weights mapping to shift-buffer row ``r`` —
+summing enable maps per non-zero weight and summing row occupancies are
+the same double sum.
 
-Like every kernel in this repo, ``interpret=True`` is the CPU fallback
-(this container is CPU-only; TPU v5e is the compile target).
+Everything that moves spikes between lanes and sublanes — the shift
+buffer's taps and row placement, and the max-pool's lane pairs — is a
+0/1 selection matmul built from ``iota`` in the kernel.  Selecting binary
+spikes is exact at any matmul precision, and it keeps every value 2-D
+with no lane-splitting reshape, which Mosaic refuses.  The FC layer
+after the last pool consumes its ``(C, W')`` input channel by channel
+against sublane-aligned row slices of its weight matrix: the flatten
+without a reshape.  Dots that carry weights run at
+``Precision.HIGHEST``, so the kernel agrees with the float32 reference
+run under ``jax.default_matmul_precision("highest")``.
+
+The kernel compiles through Mosaic on a TPU and runs in interpret mode on
+the CPU only (:mod:`repro.kernels.platform`).
 """
 from __future__ import annotations
 
@@ -40,8 +51,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = [
     "FusedConv",
@@ -89,8 +103,8 @@ class FusedConv:
     ic: int
     oc: int
     w_cm: np.ndarray           # (OC, KW*IC) f32; col r = ci*IC + ic
-    counts: np.ndarray         # (1, KW*IC) f32; nnz per shift-buffer row
-    lif: np.ndarray            # (3, OC) f32: alpha, theta, v_th
+    counts: np.ndarray         # (KW*IC, 1) f32; nnz per shift-buffer row
+    lif: np.ndarray            # (OC, 3) f32 columns: alpha, theta, v_th
     static_counts: Dict[str, int]  # Algorithm-2 reps/compute/extra/empty
 
 
@@ -140,8 +154,8 @@ def fused_conv_info(name: str, coo, lif, sched) -> FusedConv:
     return FusedConv(
         name=name, kw=coo.kw, ic=coo.ic, oc=coo.oc,
         w_cm=np.ascontiguousarray(w_cm),
-        counts=counts.astype(np.float32)[None, :],
-        lif=_lif_rows(lif, coo.oc),
+        counts=counts.astype(np.float32)[:, None],
+        lif=np.ascontiguousarray(_lif_rows(lif, coo.oc).T),
         static_counts={
             "reps_per_timestep": sched.reps,
             "compute_iters": sched.n_compute,
@@ -189,16 +203,75 @@ def fused_stack_of(plan) -> Optional[FusedStack]:
 # The kernel.
 # ---------------------------------------------------------------------------
 
-def _shift_buffer_cm(x: jax.Array, kw: int) -> jax.Array:
-    """Padded (IC, W) frame -> X'(KW*IC, W), rows ci-major (r = ci*IC+ic).
+_HIGHEST = lax.Precision.HIGHEST
 
-    pad_same + static slices: stays 2-D throughout (no rank-3 reshape for
-    Mosaic to choke on).
+
+def _select(rows: int, cols: int, fn) -> jax.Array:
+    """(rows, cols) 0/1 bf16 matrix with ones where ``fn(i, j)`` holds."""
+    i = lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    j = lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    return fn(i, j).astype(jnp.bfloat16)
+
+
+def _route(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` where one side is a 0/1 selection matrix and the other
+    binary spikes: every output is one spike or 0, exact in bf16.
+
+    The precision is explicit: under ``jax.default_matmul_precision(
+    "highest")`` a bf16 dot would otherwise ask Mosaic for an fp32
+    contraction, which it refuses for bf16 operands."""
+    return jnp.dot(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                   precision=lax.Precision.DEFAULT,
+                   preferred_element_type=jnp.float32)
+
+
+def _shift_buffer_cm(x: jax.Array, kw: int) -> jax.Array:
+    """(IC, W) spike frame -> X'(KW*IC, W), rows ci-major (r = ci*IC+ic).
+
+    Tap ``ci`` reads ``x[:, j + ci - left]`` ("same" padding, zeros out of
+    range) through a (W, W) shift matrix; a (KW*IC, IC) placement matrix
+    puts its rows at ``ci*IC ...``.  All matmuls, no pad or concatenate.
     """
     ic, w = x.shape
     left = (kw - 1) // 2
-    xp = jnp.pad(x, ((0, 0), (left, kw - 1 - left)))
-    return jnp.concatenate([xp[:, ci:ci + w] for ci in range(kw)], axis=0)
+    sb = None
+    for ci in range(kw):
+        tap = _route(x, _select(w, w, lambda i, j, o=ci - left: i == j + o))
+        part = _route(_select(kw * ic, ic,
+                              lambda r, c, o=ci * ic: r == c + o), tap)
+        sb = part if sb is None else sb + part
+    return sb
+
+
+def _max_pool(x: jax.Array, pool: int) -> jax.Array:
+    """(C, W) spikes -> (C, W // pool) max over non-overlapping windows."""
+    c, w = x.shape
+    wo = w // pool
+    out = None
+    for k in range(pool):
+        pick = _route(x, _select(w, wo, lambda i, j, k=k: i == pool * j + k))
+        out = pick if out is None else jnp.maximum(out, pick)
+    return out
+
+
+def _fc_current(x: jax.Array, w_ref) -> jax.Array:
+    """(C, W') spikes @ (C*W', OUT) weights -> (1, OUT), flattened c-major.
+
+    ``Σ_c x[c] @ w[c·W':(c+1)·W']``: a flatten without a reshape.
+    """
+    c, wp = x.shape
+    cur = None
+    for i in range(c):
+        part = jnp.dot(x[i:i + 1, :], w_ref[i * wp:(i + 1) * wp, :],
+                       precision=_HIGHEST,
+                       preferred_element_type=jnp.float32)
+        cur = part if cur is None else cur + part
+    return cur
+
+
+def _total(a: jax.Array) -> jax.Array:
+    """Sum of a 2-D array as a (1, 1) array."""
+    return jnp.sum(jnp.sum(a, axis=0, keepdims=True), axis=1, keepdims=True)
 
 
 def _lif_fire(v_acc: jax.Array, theta, v_th) -> Tuple[jax.Array, jax.Array]:
@@ -221,12 +294,15 @@ def stream_fused_forward(
     modulator turns into spikes in-kernel (one frame read per *sample*
     instead of per timestep).
 
+    ``interpret=None`` follows :func:`repro.kernels.platform.interpret_mode`;
+    ``False`` compiles for a TPU even from a CPU process (ahead-of-time
+    compiles against a described chip).
+
     Returns ``(logits (B, n_classes), conv_accs (B, n_convs))`` where
     ``conv_accs`` are the gated-accumulation counters of paper Tables
     I/III, per sample and conv layer (see :func:`fused_counters`).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     t_steps = stack.timesteps
     if encode:
         b, ic0, w0 = frames.shape
@@ -256,10 +332,8 @@ def stream_fused_forward(
     if encode:
         scratch_shapes += [pltpu.VMEM((ic0, w0), jnp.float32)] * 2
         scratch_dims += [(ic0, w0)] * 2
-    width, chans = w0, ic0
-    layer_widths = []                 # input width at each layer
+    width = w0
     for layer in stack.layers:
-        layer_widths.append(width)
         if isinstance(layer, FusedConv):
             for a in (layer.w_cm, layer.counts, layer.lif):
                 arr = jnp.asarray(a)
@@ -267,7 +341,6 @@ def stream_fused_forward(
                 in_specs.append(whole(arr))
             scratch_shapes.append(pltpu.VMEM((layer.oc, width), jnp.float32))
             scratch_dims.append((layer.oc, width))
-            chans = layer.oc
         elif isinstance(layer, FusedPool):
             width = (width // layer.pool)
         elif isinstance(layer, FusedFC):
@@ -278,15 +351,16 @@ def stream_fused_forward(
             dout = layer.w.shape[1]
             scratch_shapes.append(pltpu.VMEM((1, dout), jnp.float32))
             scratch_dims.append((1, dout))
-            chans, width = dout, 1
 
+    # a singleton middle axis makes each (1, 1, n) block's last two dims
+    # equal the array's, as Mosaic requires for any batch size
     out_shape = [
-        jax.ShapeDtypeStruct((b, stack.n_classes), jnp.float32),
-        jax.ShapeDtypeStruct((b, n_convs), jnp.float32),
+        jax.ShapeDtypeStruct((b, 1, stack.n_classes), jnp.float32),
+        jax.ShapeDtypeStruct((b, 1, n_convs), jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, stack.n_classes), lambda bb, tt: (bb, 0)),
-        pl.BlockSpec((1, n_convs), lambda bb, tt: (bb, 0)),
+        pl.BlockSpec((1, 1, stack.n_classes), lambda bb, tt: (bb, 0, 0)),
+        pl.BlockSpec((1, 1, n_convs), lambda bb, tt: (bb, 0, 0)),
     ]
 
     def kernel(*refs):
@@ -314,8 +388,8 @@ def stream_fused_forward(
 
         @pl.when(t == 0)
         def _fresh_sample():
-            logits_ref[...] = jnp.zeros_like(logits_ref[...])
-            accs_ref[...] = jnp.zeros_like(accs_ref[...])
+            logits_ref[...] = jnp.zeros(logits_ref.shape, jnp.float32)
+            accs_ref[...] = jnp.zeros(accs_ref.shape, jnp.float32)
             for ref, dims in zip(scratch, scratch_dims):
                 ref[...] = jnp.zeros(dims, jnp.float32)
 
@@ -331,49 +405,43 @@ def stream_fused_forward(
         else:
             x = x_ref[0, 0]
 
-        acc_contribs = []
+        lane = lax.broadcasted_iota(jnp.int32, (1, n_convs), 1)
+        accs = jnp.zeros((1, n_convs), jnp.float32)
+        i_conv = 0
         last_cur = None
-        for layer, lrefs, w_in in zip(stack.layers, layer_refs,
-                                      layer_widths):
+        for layer, lrefs in zip(stack.layers, layer_refs):
             if isinstance(layer, FusedConv):
                 w_ref, c_ref, lif_ref = lrefs
                 v_ref = scratch[sc]
                 sc += 1
                 sb = _shift_buffer_cm(x, layer.kw)          # (KW*IC, W)
-                cur = jnp.dot(w_ref[...], sb,
+                cur = jnp.dot(w_ref[...], sb, precision=_HIGHEST,
                               preferred_element_type=jnp.float32)
-                acc_contribs.append(
-                    jnp.sum(c_ref[...] * jnp.sum(sb, axis=1)[None, :]))
-                lif = lif_ref[...]                          # (3, OC)
-                v_acc = lif[0][:, None] * v_ref[...] + cur
-                v_next, x = _lif_fire(v_acc, lif[1][:, None],
-                                      lif[2][:, None])
+                occupancy = jnp.sum(sb, axis=1, keepdims=True)
+                accs = accs + jnp.where(
+                    lane == i_conv, _total(c_ref[...] * occupancy), 0.0)
+                i_conv += 1
+                lif = lif_ref[...]                          # (OC, 3)
+                v_acc = lif[:, 0:1] * v_ref[...] + cur
+                v_next, x = _lif_fire(v_acc, lif[:, 1:2], lif[:, 2:3])
                 v_ref[...] = v_next
             elif isinstance(layer, FusedPool):
-                c = x.shape[0]
-                w2 = (w_in // layer.pool) * layer.pool
-                x = (x[:, :w2]
-                     .reshape(c * (w2 // layer.pool), layer.pool)
-                     .max(axis=1)
-                     .reshape(c, w2 // layer.pool))
+                x = _max_pool(x, layer.pool)
             elif isinstance(layer, FusedFC):
                 w_ref, lif_ref = lrefs
                 v_ref = scratch[sc]
                 sc += 1
-                cur = jnp.dot(x.reshape(1, -1), w_ref[...],
-                              preferred_element_type=jnp.float32)
+                cur = _fc_current(x, w_ref)                 # (1, OUT)
                 lif = lif_ref[...]                          # (3, OUT)
-                v_acc = lif[0][None, :] * v_ref[...] + cur
-                v_next, x = _lif_fire(v_acc, lif[1][None, :],
-                                      lif[2][None, :])
+                v_acc = lif[0:1, :] * v_ref[...] + cur
+                v_next, x = _lif_fire(v_acc, lif[1:2, :], lif[2:3, :])
                 v_ref[...] = v_next
                 last_cur = cur
             else:  # FusedReadout
                 contrib = last_cur if layer.mode == "current_sum" else x
-                logits_ref[...] = logits_ref[...] + contrib.reshape(
-                    1, stack.n_classes)
-        if acc_contribs:
-            accs_ref[...] = accs_ref[...] + jnp.stack(acc_contribs)[None, :]
+                logits_ref[0] = logits_ref[0] + contrib
+        if i_conv:
+            accs_ref[0] = accs_ref[0] + accs
 
     logits, accs = pl.pallas_call(
         kernel,
@@ -385,7 +453,7 @@ def stream_fused_forward(
         interpret=interpret,
         name="stream_fused",
     )(*inputs)
-    return logits, accs
+    return logits[:, 0], accs[:, 0]
 
 
 def fused_counters(stack: FusedStack, accs_row: jax.Array) -> Dict[str, Dict]:

@@ -14,10 +14,13 @@ each output tile accumulates in VMEM across IN-tiles (revisiting pattern).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["wm_fc_matmul"]
 
@@ -30,7 +33,8 @@ def _kernel(s_ref, w_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += jnp.dot(
-        s_ref[...], w_ref[...], preferred_element_type=out_ref.dtype
+        s_ref[...], w_ref[...], precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=out_ref.dtype,
     )
 
 
@@ -44,7 +48,7 @@ def wm_fc_matmul(
     block_b: int = 8,
     block_in: int = 128,
     block_out: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     b, d_in = spikes.shape
     d_in2, d_out = weights.shape
@@ -66,7 +70,7 @@ def wm_fc_matmul(
         ],
         out_specs=pl.BlockSpec((block_b, block_out), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((s.shape[0], w.shape[1]), weights.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
         name="wm_fc_matmul",
     )(s, w)
     return out[:b, :d_out]

@@ -16,9 +16,8 @@ TP/SP/EP (see distributed/sharding.py).
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
-
-from repro.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_dev_mesh", "HW"]
 
@@ -36,11 +35,12 @@ HW = {
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_dev_mesh(data: int = 2, model: int = 4) -> Mesh:
     """Small mesh for CPU multi-device tests (needs host_device_count)."""
-    return make_mesh(
+    return jax.make_mesh(
         (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
     )

@@ -28,9 +28,7 @@ def run(arch: str = "llama3-8b", n_micro: int = 16, seq: int = 4096,
         batch: int = 32) -> dict:
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.configs.registry import get_config
     from repro.distributed.ctx import activation_constraints
@@ -47,8 +45,8 @@ def run(arch: str = "llama3-8b", n_micro: int = 16, seq: int = 4096,
     n_stages = 8
     assert cfg.n_layers % n_stages == 0
     per_stage = cfg.n_layers // n_stages
-    mesh = make_mesh((n_stages, 2, 16), ("stage", "data", "model"),
-                     axis_types=(AxisType.Auto,) * 3)
+    mesh = jax.make_mesh((n_stages, 2, 16), ("stage", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     chips = len(mesh.devices.flat)
     mb = batch // n_micro
 

@@ -144,6 +144,9 @@ def main(argv=None) -> int:
                          "completed spans as Chrome trace-event JSON "
                          "(loadable in ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import place_compile_cache
+
+    place_compile_cache()
 
     if args.arch == "saocds-amc":
         from repro.configs.saocds_amc import CONFIG
@@ -323,6 +326,10 @@ def main(argv=None) -> int:
                 t = ", ".join(f"{k}={v:.1f}ms"
                               for k, v in engine.autotune.timings_ms.items())
                 print(f"autotune[{t}] -> {engine.backend}")
+                # a candidate the compiler refused is dropped from the race;
+                # say so, or the log hides that a kernel never ran
+                for k, err in engine.autotune.errors.items():
+                    print(f"autotune: {k} failed: {err}")
             if getattr(engine, "perlayer", None) is not None:
                 a = ", ".join(f"{k}={v}"
                               for k, v in engine.assignment.items())

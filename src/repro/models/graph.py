@@ -31,7 +31,7 @@ name          per-layer implementation
 dense         im2col matmul oracle (differentiable; masks + LSQ quant)
 goap          packed COO one-to-all product (Algorithm 1 as one fused
               gather + contraction per timestep)
-pallas        static block-sparse TPU kernel (CPU ``interpret`` fallback)
+pallas        static block-sparse TPU kernel (interpret mode on the CPU)
 pallas_fused  same per-layer cells, plus kernel-ready operands for the
               whole-network multi-layer streaming kernel
               (:mod:`repro.kernels.stream_fused`) — the fused executor
@@ -491,7 +491,8 @@ register_backend("goap", KIND_FC, _dense_fc)
 
 
 # ---------------------------------------------------------------------------
-# pallas backend — static block-sparse TPU kernel (interpret=True on CPU).
+# pallas backend — static block-sparse TPU kernel (compiled on a TPU,
+# interpret mode on the CPU: repro.kernels.platform).
 # ---------------------------------------------------------------------------
 
 PALLAS_BLOCK_OC = 8

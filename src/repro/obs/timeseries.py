@@ -1,7 +1,7 @@
 """Bounded time-series recording over a metrics registry (analysis plane).
 
 The registry (:mod:`repro.obs.metrics`) is a point-in-time scrape: it can
-say "789930 accumulations so far" but not "effective density fell 30% in
+say "650020 accumulations so far" but not "effective density fell 30% in
 the last minute".  The paper's central claim — throughput and power
 tracking *effective* input/weight sparsity — is only verifiable over
 time, and so is every question the alerting layer asks (burn rates,

@@ -274,7 +274,7 @@ class ExecutionPlan:
         vmapped streaming path extracts only the ``accumulations`` array
         leaf inside the closure so the static int leaves of the counter
         dict never hit vmap.  Counts are float32 throughout; exact below
-        2**24 events/frame (paper config peaks at 437602).
+        2**24 events/frame (paper config peaks at 306721).
         """
         stack = self.fused_stack()
         if stack is not None:

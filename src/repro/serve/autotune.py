@@ -5,7 +5,7 @@ The layer-graph API makes every execution dataflow interchangeable
 but their *cost* is wildly platform-dependent: the COO gather dataflow that
 wins on the paper's accelerator loses to the im2col matmul oracle on a
 wide-SIMD CPU, and the Pallas block-sparse kernel only pays off on a real
-TPU (CPU interpret mode executes the kernel body in Python).
+TPU (on the CPU the kernels run in interpret mode).
 
 So the engine does what the hardware cannot: at bind time it **races** the
 candidate backends on the exact batch shape it is about to serve — compile,
@@ -45,8 +45,10 @@ __all__ = [
     "autotune_per_layer",
 ]
 
-# Interpret-mode Pallas is orders of magnitude off the pace and only slows
-# the race down; only let it compete where a real TPU will run it.
+# Pallas kernels run in interpret mode on the CPU (repro.kernels.platform),
+# orders of magnitude off the pace: they compete only on a TPU, where they
+# compile.  A candidate the chip's compiler refuses lands in
+# ``AutotuneReport.errors``, which the serve driver prints.
 _CPU_CANDIDATES = ("dense", "goap")
 _TPU_CANDIDATES = ("dense", "goap", "pallas", "pallas_fused")
 

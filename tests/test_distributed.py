@@ -16,9 +16,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs.registry import get_config
 from repro.distributed.compression import (
@@ -46,7 +44,7 @@ def _run_py(code: str, devices: int = 8, timeout: int = 600):
 # ---------------------------------------------------------------------------
 
 def _mesh():
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_partition_rules_tp_and_fsdp():
@@ -161,10 +159,10 @@ def test_compressed_psum_two_workers():
     res = _run_py("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from repro.compat import AxisType, make_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.distributed.compression import compressed_psum
-        mesh = make_mesh((2,), ("dp",), axis_types=(AxisType.Auto,))
-        @functools.partial(shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")),
+        mesh = jax.make_mesh((2,), ("dp",), axis_types=(AxisType.Auto,))
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")),
                            out_specs=(P("dp"), P("dp")), check_vma=False)
         def step(g, ef):
             g0 = {"w": g[0]}
@@ -192,9 +190,9 @@ def test_compressed_psum_two_workers():
 def test_spmd_pipeline_equals_sequential():
     res = _run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import AxisType, make_mesh
+        from jax.sharding import AxisType
         from repro.distributed.pipeline import spmd_pipeline
-        mesh = make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         ws = jnp.asarray(rng.normal(size=(4, 16, 16)).astype(np.float32) * 0.3)
         def stage_fn(w, x):
@@ -219,10 +217,10 @@ def test_elastic_checkpoint_reshard(tmp_path):
     res = _run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import AxisType, make_mesh
+        from jax.sharding import AxisType
         from repro.train.checkpoint import CheckpointManager
-        meshA = make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
-        meshB = make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        meshA = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+        meshB = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,)*2)
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         xa = jax.device_put(x, NamedSharding(meshA, P("data", "model")))
         ck = CheckpointManager(r"{tmp_path}", keep=2)

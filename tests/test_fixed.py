@@ -27,10 +27,12 @@ from repro.api import SNNConfig, init_snn
 from repro.fixed import (
     FixedQuantFn,
     build_golden,
+    calibrate_step,
     fixed_encode_batch,
     fixed_logit_scale,
     fixed_sigma_delta_encode,
     golden_encode_frames,
+    quantize_codes,
 )
 from repro.models.graph import available_backends, compile_snn
 from repro.plan import PlanCache, compile_plan
@@ -136,6 +138,25 @@ def test_artifact_cache_hit_stays_bit_exact():
                       assignment="fixed", cache=cache)
     assert np.array_equal(np.asarray(p1.bound.batch(enc)),
                           np.asarray(p2.bound.batch(enc)))
+
+
+@pytest.mark.parametrize("bits", [8, 16])
+def test_fixed_quant_fn_quantizes_concrete_weights_on_the_host(bits):
+    """Concrete weights fake-quantize in NumPy, bit-identical to the codes
+    the golden derives: a device division may round a weight that sits on
+    a half step the other way.  Traced weights keep the jnp path."""
+    rng = np.random.default_rng(bits)
+    w = rng.normal(size=(64, 32)).astype(np.float32)
+    qfn = FixedQuantFn(None, bits=bits)
+    got = qfn(w)
+    assert isinstance(got, np.ndarray) and got.dtype == np.float32
+    step = calibrate_step(w, bits)
+    want = quantize_codes(w, step, bits).astype(np.float32) * np.float32(step)
+    assert np.array_equal(got, want)
+    scales = init_lsq_scales({"conv": [{"w": w}], "fc": []}, bits)
+    traced = jax.jit(FixedQuantFn(scales, bits=bits))(jnp.asarray(w))
+    assert np.array_equal(np.asarray(traced),
+                          FixedQuantFn(scales, bits=bits)(w))
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])

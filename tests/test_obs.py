@@ -397,6 +397,23 @@ def test_activity_gauges_match_stream_goldens():
     assert np.asarray(logits).shape[0] == 1
 
 
+def test_activity_observer_totals_exact_past_float32(weights):
+    """A batch whose summed counts pass 2**24 must still total exactly:
+    a float32 sum of 64 paper-config frames (~262k events each) rounds."""
+    params, masks = weights
+    plan = compile_plan(compile_snn(CFG), params, masks=masks,
+                        assignment="stream", cache=PlanCache(disk_dir=""))
+    counts = (262144 + np.random.default_rng(0).integers(0, 4000, 64)
+              ).astype(np.float32)
+    want = int(counts.astype(np.int64).sum())
+    assert int(counts.sum()) != want          # the float32 sum rounds here
+    reg = MetricsRegistry()
+    obs = ActivityObserver(plan, registry=reg, engine="wide")
+    obs.observe({"conv1": counts}, n_real=64)
+    assert reg.value("repro_activity_accumulations_total", engine="wide",
+                     layer="conv1") == want
+
+
 def test_batch_counters_fused_matches_stream(weights):
     """The fused stack's per-row counters agree with the interpreter's."""
     params, masks = weights

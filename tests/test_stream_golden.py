@@ -27,17 +27,18 @@ DENSITY = 0.5
 # Per-layer static schedule geometry (input-independent: fixed by the
 # masked weights alone) and the gated accumulation counts for the seeded
 # input below.  nnz at 50%: conv1 11*2*16/2 = 176, conv2 11*16*32/2 =
-# 2816, conv3 5*32*64/2 = 5120 (+1 empty stall slot while I[1] streams in).
+# 2816, conv3 5*32*64/2 = 5120.  Cut with the default JAX PRNG
+# (``jax_threefry_partitionable`` on), which seeds ``init_snn``.
 GOLDEN_LAYERS = {
     "conv1": {"reps_per_timestep": 176, "compute_iters": 176,
-              "extra_iters": 0, "empty_iters": 0, "accumulations": 88895},
+              "extra_iters": 0, "empty_iters": 0, "accumulations": 89015},
     "conv2": {"reps_per_timestep": 2816, "compute_iters": 2816,
-              "extra_iters": 0, "empty_iters": 0, "accumulations": 437602},
-    "conv3": {"reps_per_timestep": 5121, "compute_iters": 5120,
-              "extra_iters": 0, "empty_iters": 1, "accumulations": 263433},
+              "extra_iters": 0, "empty_iters": 0, "accumulations": 306721},
+    "conv3": {"reps_per_timestep": 5120, "compute_iters": 5120,
+              "extra_iters": 0, "empty_iters": 0, "accumulations": 254284},
 }
-GOLDEN_TOTALS = {"compute_iters": 8112, "extra_iters": 0, "empty_iters": 1,
-                 "reps_per_timestep": 8113, "accumulations": 789930}
+GOLDEN_TOTALS = {"compute_iters": 8112, "extra_iters": 0, "empty_iters": 0,
+                 "reps_per_timestep": 8112, "accumulations": 650020}
 
 
 def _setup():

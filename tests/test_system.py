@@ -40,9 +40,9 @@ def test_shapes_match_assignment():
 
 def test_production_mesh_axes():
     """The assigned mesh layouts (AbstractMesh: no device init)."""
-    from repro.compat import abstract_mesh
+    from jax.sharding import AbstractMesh
 
-    single = abstract_mesh((16, 16), ("data", "model"))
-    multi = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    single = AbstractMesh((16, 16), ("data", "model"))
+    multi = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert dict(single.shape) == {"data": 16, "model": 16}
     assert dict(multi.shape) == {"pod": 2, "data": 16, "model": 16}
