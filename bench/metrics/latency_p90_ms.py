@@ -1,0 +1,7 @@
+"""90th percentile due-to-resolution latency of the requests due in the window."""
+import measure
+
+
+def read(run):
+    lat = measure.latencies_s(run)
+    return measure.percentile(lat, 90) * 1e3 if lat.size else None

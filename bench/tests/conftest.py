@@ -1,0 +1,12 @@
+"""Put the benchmark's modules and the program's sources on the path.
+
+Run the benchmark's own tests explicitly: ``python -m pytest bench/tests``.
+"""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for path in (BENCH, os.path.join(ROOT, "src")):
+    if path not in sys.path:
+        sys.path.insert(0, path)
