@@ -63,6 +63,8 @@ from repro.obs.trace import (
     disable_tracing,
     enable_tracing,
     get_tracer,
+    install_process_telemetry,
+    span,
     tadd,
     tfinish,
 )
@@ -82,6 +84,8 @@ __all__ = [
     "begin_trace",
     "tadd",
     "tfinish",
+    "span",
+    "install_process_telemetry",
     "ActivityObserver",
     "static_schedule_counts",
     "SCHEDULE_KEYS",

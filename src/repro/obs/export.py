@@ -20,11 +20,7 @@ Layout:
   reuses the lowest lane whose previous request already ended;
 * ``ts`` is microseconds on a common axis (the dump's ``t0`` anchors,
   normalized to the earliest event so Perfetto opens at t=0);
-* ``M``etadata events name the tracks;
-* per-layer timings from
-  :func:`~repro.plan.streaming.profile_layer_steps` land as ``X``
-  (complete) events on a dedicated ``layers`` process so kernel-level
-  cost sits beside request-level latency.
+* ``M``etadata events name the tracks.
 
 :func:`validate_perfetto` is the schema gate shared by the tests, the
 bench, and the obs-smoke CI job: required keys, monotonic ``ts`` per
@@ -33,7 +29,7 @@ track, and strictly matching ``B``/``E`` pairs.
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Tuple
 
 __all__ = ["to_perfetto", "write_perfetto", "validate_perfetto"]
 
@@ -70,11 +66,8 @@ class _LaneAllocator:
         return len(self._lanes) - 1
 
 
-def to_perfetto(dump: Dict[str, Any],
-                layer_ms: Optional[Dict[str, float]] = None
-                ) -> Dict[str, Any]:
-    """Convert a :meth:`TraceLog.dump` dict (+ optional per-layer ms
-    from ``profile_layer_steps``) to Chrome trace-event JSON."""
+def to_perfetto(dump: Dict[str, Any]) -> Dict[str, Any]:
+    """Convert a :meth:`TraceLog.dump` dict to Chrome trace-event JSON."""
     traces = [t for t in dump.get("traces", []) if t.get("events")]
     # absolute event times: t0 + t_rel_s (older dumps without t0 still
     # render, each anchored at its own zero)
@@ -134,27 +127,11 @@ def to_perfetto(dump: Dict[str, Any],
         events.append({"ph": "E", "pid": pid, "tid": tid, "ts": t_end,
                        "cat": "request"})
 
-    if layer_ms:
-        pid = pid_of("layers")
-        events.append({"ph": "M", "name": "thread_name", "pid": pid,
-                       "tid": 1, "args": {"name": "per-layer step"}})
-        # sequential X spans: one profiled step per layer, end to end
-        cursor = 0.0
-        for layer, ms in layer_ms.items():
-            dur = float(ms) * 1000.0      # ms -> us
-            events.append({"ph": "X", "name": layer, "pid": pid,
-                           "tid": 1, "ts": cursor, "dur": dur,
-                           "cat": "layer",
-                           "args": {"ms_per_step": float(ms)}})
-            cursor += dur
-
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
-def write_perfetto(path: str, dump: Dict[str, Any],
-                   layer_ms: Optional[Dict[str, float]] = None
-                   ) -> Dict[str, Any]:
-    doc = to_perfetto(dump, layer_ms=layer_ms)
+def write_perfetto(path: str, dump: Dict[str, Any]) -> Dict[str, Any]:
+    doc = to_perfetto(dump)
     with open(path, "w") as f:
         json.dump(doc, f)
     return doc

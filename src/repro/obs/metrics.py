@@ -29,7 +29,8 @@ import bisect
 import math
 import re
 import threading
-from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterable, List, Mapping, Optional,
+                    Sequence, Tuple)
 
 __all__ = [
     "MetricsRegistry",
@@ -127,6 +128,23 @@ class _Histogram:
             self.count += 1
 
 
+class _Pulled:
+    """A counter child read from a function on every read.
+
+    For totals kept where no lock may be taken: a garbage-collector
+    callback can run while its thread holds any lock, this registry's
+    included.
+    """
+    __slots__ = ("_read",)
+
+    def __init__(self, read: Callable[[], float]):
+        self._read = read
+
+    @property
+    def value(self) -> float:
+        return float(self._read())
+
+
 _CHILD_TYPES = {"counter": _Counter, "gauge": _Gauge, "histogram": _Histogram}
 
 
@@ -180,6 +198,15 @@ class _Family:
 
     def observe(self, value: float) -> None:
         self._solo().observe(value)
+
+    def pull(self, read: Callable[[], float], **labelvalues: str) -> None:
+        """Make one counter child read its value from ``read()``."""
+        if self.kind != "counter":
+            raise ValueError(f"{self.name}: pull is counter-only")
+        self.labels(**labelvalues)    # validates the label names
+        key = tuple(str(labelvalues[n]) for n in self.labelnames)
+        with self._lock:
+            self._children[key] = _Pulled(read)
 
     def set_exclusive(self, **labelvalues: str) -> None:
         """Gauge-info pattern: set the matching child to 1, all others 0

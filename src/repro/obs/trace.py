@@ -12,7 +12,15 @@ between consecutive events — :meth:`RequestTrace.spans` derives them, so
 queueing delay vs batch-forming delay vs jitted-step time are separable
 per request, fleet-wide.
 
-Cost model: tracing is **off by default** and the hot path pays one
+The serving worker's phases and the garbage collector are traced a
+second way, on the JAX profiler's own clock: :func:`span` opens a
+``jax.profiler.TraceAnnotation``, recorded in the profile beside the
+device's events when one is being captured (``jax.profiler.start_trace``
+or ``start_server``) and recording nothing when none is.
+:func:`install_process_telemetry` adds a ``host.gc`` span per garbage
+collection and the collection counters.
+
+Cost model: request tracing is **off by default** and the hot path pays one
 module-global read per request when disabled.  When enabled
 (:func:`enable_tracing`), the deterministic ``sample_every`` knob traces
 every Nth submission; completed traces land in a bounded ring buffer
@@ -26,10 +34,15 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import itertools
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import default_registry
 
 __all__ = [
     "TraceEvent",
@@ -42,6 +55,8 @@ __all__ = [
     "begin_trace",
     "tadd",
     "tfinish",
+    "span",
+    "install_process_telemetry",
 ]
 
 #: Event names that end a request's timeline.
@@ -212,3 +227,73 @@ def tadd(trace: Optional[RequestTrace], name: str,
 def tfinish(trace: Optional[RequestTrace]) -> None:
     if trace is not None:
         trace.finish()
+
+
+# -- profiler spans and process telemetry ------------------------------------
+
+
+def span(name: str, **attrs) -> TraceAnnotation:
+    """A span ``name`` (with ``attrs``) on the profiler's clock.
+
+    Recorded only while a profile is captured.
+    """
+    return TraceAnnotation(name, **attrs)
+
+
+_GC_GENERATIONS = 3
+
+# Process-wide totals.  The collector's callback runs while its thread
+# may hold any lock, so it updates these plain lists (the interpreter
+# runs one collection at a time) and the registry reads them (pulled
+# counters); it never takes a lock itself.
+_gc_collections = [0] * _GC_GENERATIONS
+_gc_pause_s = [0.0] * _GC_GENERATIONS
+_gc_open: Optional[tuple] = None          # (annotation, start) in progress
+_telemetry_lock = threading.Lock()
+_telemetry_hooked = False
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    global _gc_open
+    if phase == "start":
+        ann = None
+        if TraceAnnotation.is_enabled():      # a profile is being captured
+            ann = TraceAnnotation("host.gc", generation=info["generation"])
+            ann.__enter__()
+        _gc_open = (ann, time.perf_counter())
+    elif _gc_open is not None:
+        ann, t0 = _gc_open
+        _gc_open = None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        gen = info["generation"]
+        _gc_collections[gen] += 1
+        _gc_pause_s[gen] += time.perf_counter() - t0
+
+
+def install_process_telemetry() -> None:
+    """Trace and count the garbage collector (hook installed once).
+
+    Each garbage collection becomes a ``host.gc`` span (``generation``)
+    and feeds ``repro_gc_collections_total{generation}`` and
+    ``repro_gc_pause_seconds_total{generation}``: process-wide totals
+    since the hook went in, exposed in the current default registry on
+    every call.
+    """
+    global _telemetry_hooked
+    with _telemetry_lock:
+        if not _telemetry_hooked:
+            gc.callbacks.append(_on_gc)
+            _telemetry_hooked = True
+    reg = default_registry()
+    collections_total = reg.counter(
+        "repro_gc_collections_total", "Garbage collections, by generation",
+        ("generation",))
+    pause_total = reg.counter(
+        "repro_gc_pause_seconds_total",
+        "Seconds the garbage collector held the interpreter, by generation",
+        ("generation",))
+    for gen in range(_GC_GENERATIONS):
+        collections_total.pull(lambda g=gen: _gc_collections[g],
+                               generation=str(gen))
+        pause_total.pull(lambda g=gen: _gc_pause_s[g], generation=str(gen))

@@ -18,13 +18,13 @@ Tables I/III counters come out identical.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
 
 from repro.models.graph import KIND_READOUT, LayerCell, timestep_template
 
-__all__ = ["init_stream_states", "run_streaming", "profile_layer_steps"]
+__all__ = ["init_stream_states", "run_streaming"]
 
 
 def init_stream_states(cells: Sequence[LayerCell], x0) -> Tuple:
@@ -91,57 +91,3 @@ def run_streaming(plan, frames: jax.Array):
         else:
             counters[lp.spec.name] = out
     return (logits if logits is not None else ys), counters
-
-
-def profile_layer_steps(plan, frames: jax.Array, reps: int = 3,
-                        registry=None) -> Dict[str, float]:
-    """Wall-time each layer's jitted per-timestep step in isolation (ms).
-
-    An *offline* observability hook — never on the serving path (the
-    fused scan has no per-layer boundaries to time).  Each cell's
-    ``step`` is jitted and timed standalone on the real state/input
-    templates this plan would stream through it: one warm-up call pays
-    compilation, then the best of ``reps`` timed loops over T timesteps
-    is attributed to the layer.  Results land in the
-    ``repro_plan_layer_step_ms{layer,backend}`` gauge (per-layer cost
-    split — where the streaming milliseconds actually go) and come back
-    as ``{layer_name: ms_per_T_timesteps}``.
-    """
-    import time
-
-    from repro.obs.metrics import MetricsRegistry, default_registry
-
-    reg: Optional[MetricsRegistry]
-    reg = registry if registry is not None else default_registry()
-    gauge = reg.gauge(
-        "repro_plan_layer_step_ms",
-        "Isolated jitted per-layer step time over T timesteps (ms)",
-        ("layer", "backend"))
-
-    cells = [lp.cell for lp in plan.layers]
-    states0 = init_stream_states(cells, timestep_template(frames))
-
-    out: Dict[str, float] = {}
-    # concrete zero input (templates are abstract ShapeDtypeStructs; a
-    # jitted call needs real arrays) — layer l+1's template is layer l's
-    # actual output, so shapes chain exactly as run_streaming's would
-    x = jax.tree_util.tree_map(
-        lambda s: jax.numpy.zeros(s.shape, s.dtype),
-        timestep_template(frames))
-    for lp, state0 in zip(plan.layers, states0):
-        step = jax.jit(lp.cell.step)
-        state, y = step(state0, x)          # warm-up: compile + templates
-        jax.block_until_ready(y)
-        best = float("inf")
-        for _ in range(max(1, reps)):
-            state = state0
-            t0 = time.perf_counter()
-            for t in range(frames.shape[0]):
-                state, y = step(state, x)
-            jax.block_until_ready(y)
-            best = min(best, time.perf_counter() - t0)
-        ms = best * 1e3
-        out[lp.spec.name] = ms
-        gauge.labels(layer=lp.spec.name, backend=lp.backend).set(ms)
-        x = y                               # next layer's input template
-    return out

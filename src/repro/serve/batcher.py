@@ -53,7 +53,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.obs.trace import tadd, tfinish
+from repro.obs.trace import span, tadd, tfinish
 
 __all__ = [
     "ServeFuture",
@@ -457,16 +457,17 @@ class MicroBatcher:
             if out is None:
                 return None
             reqs, depth = out
-            bucket = bucket_for(len(reqs), self.buckets)
-            frames = np.zeros((bucket,) + self.frame_shape,
-                              dtype=np.float32)
-            # trace timestamps stay on perf_counter even under a fake
-            # batcher clock — spans must be comparable across events
-            t_form = time.perf_counter()
-            for i, r in enumerate(reqs):
-                frames[i] = r.iq
-                tadd(r.trace, "batch-form", t=t_form, bucket=bucket,
-                     n_real=len(reqs), n_padded=bucket - len(reqs))
+            with span("batcher.form", n_real=len(reqs)):
+                bucket = bucket_for(len(reqs), self.buckets)
+                frames = np.zeros((bucket,) + self.frame_shape,
+                                  dtype=np.float32)
+                # trace timestamps stay on perf_counter even under a fake
+                # batcher clock — spans must be comparable across events
+                t_form = time.perf_counter()
+                for i, r in enumerate(reqs):
+                    frames[i] = r.iq
+                    tadd(r.trace, "batch-form", t=t_form, bucket=bucket,
+                         n_real=len(reqs), n_padded=bucket - len(reqs))
             return MicroBatch(requests=reqs, bucket=bucket, frames=frames,
                               queue_depth=depth)
 

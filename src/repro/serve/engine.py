@@ -66,7 +66,13 @@ from repro.serve.autotune import (
 )
 from repro.obs.activity import ActivityObserver
 from repro.obs.metrics import default_registry
-from repro.obs.trace import begin_trace, tadd, tfinish
+from repro.obs.trace import (
+    begin_trace,
+    install_process_telemetry,
+    span,
+    tadd,
+    tfinish,
+)
 from repro.serve.batcher import EngineClosed, MicroBatcher, QueueFull
 
 __all__ = ["AMCServeEngine", "AsyncAMCServeEngine", "ServeStats",
@@ -391,6 +397,7 @@ class AsyncAMCServeEngine:
 
         # registry instrumentation: all families are idempotent creates on
         # the process-wide registry, children pre-resolved off the hot path
+        install_process_telemetry()
         reg = default_registry()
         eng = self.name
         self._m_requests = reg.counter(
@@ -593,8 +600,18 @@ class AsyncAMCServeEngine:
             return ver if ver is not None else self._versions[self._primary]
 
     def _worker(self) -> None:
+        # one profiler span per phase of each batch, tiling the loop:
+        # engine.gather (batcher.form inside it), engine.put,
+        # engine.dispatch (to the step's asynchronous return), engine.fetch
+        # (the wait for the device and the copy back), engine.counters
+        # (live activity counters only), engine.resolve.  The input array
+        # is freed right after dispatch and a batch's outputs stay bound
+        # until the next batch rebinds them: freeing a device array
+        # releases the interpreter lock, which right after the futures
+        # resolve the client would take for its whole refill.
         while not self._stop.is_set():
-            batch = self.batcher.get_batch(timeout=0.1)
+            with span("engine.gather"):
+                batch = self.batcher.get_batch(timeout=0.1)
             if batch is None:
                 continue
             t_busy0 = time.perf_counter()
@@ -606,83 +623,91 @@ class AsyncAMCServeEngine:
                 # raises, the batch's futures fail instead of stranding.
                 ver = self._route()
                 t_step0 = time.perf_counter()
-                out = ver.step(jnp.asarray(batch.frames))
+                with span("engine.put"):
+                    x = jnp.asarray(batch.frames)
+                with span("engine.dispatch", bucket=batch.bucket,
+                          n_real=batch.n_real, backend=ver.backend):
+                    out = ver.step(x)
+                    del x   # now, not at the next batch's put
                 if ver.activity is not None:
                     logits_dev, accs = out
-                    logits = np.asarray(logits_dev)
                 else:
-                    accs = None
-                    logits = np.asarray(out)
+                    logits_dev, accs = out, None
+                with span("engine.fetch"):
+                    logits = np.asarray(logits_dev)
                 t_step1 = time.perf_counter()
                 self._ready.set()  # first successful jit step: /readyz 200
                 preds = logits.argmax(-1).astype(np.int32)
                 n_real = batch.n_real
                 if accs is not None:
-                    ver.activity.observe(
-                        {k: np.asarray(v) for k, v in accs.items()}, n_real)
-                # activity counting is an expensive diagnostics mode; it
-                # runs outside the lock (workers stay parallel) but before
-                # the futures resolve, so a caller that reads ``stats``
-                # right after its results always sees them counted
-                counted: Optional[ServeStats] = None
-                if self.count_activity and ver.sparse is not None:
-                    counted = ServeStats()
-                    frames = sigma_delta_encode_np(
-                        batch.frames[:n_real], self.cfg.timesteps)
-                    count_batch_activity(counted, ver.sparse, frames,
-                                         self.cfg)
-                # completion is stamped after counting: callers' futures
-                # resolve after it, so latencies reflect what they waited
-                t_done = time.perf_counter()
-                with self._lock:
-                    # serving window: first enqueue ever -> latest batch
-                    # completion.  Correct for both the submit()/future
-                    # path and (possibly concurrent) classify() callers.
-                    # Each version additionally tracks its own window so a
-                    # late-bound canary's throughput is not diluted.
-                    batch_first = min(r.t_enqueue for r in batch.requests)
-                    self._t_first_enqueue = min(self._t_first_enqueue,
-                                                batch_first)
-                    ver.t_first = min(ver.t_first, batch_first)
-                    for st, t0 in ((self.stats, self._t_first_enqueue),
-                                   (ver.stats, ver.t_first)):
-                        st.requests += n_real
-                        st.record_batch(ver.backend,
-                                        queue_depth=batch.queue_depth,
-                                        padded=batch.n_padded)
-                        st.record_latencies(
-                            t_done - r.t_enqueue for r in batch.requests)
-                        # max(): a worker delayed by activity counting must
-                        # not shrink a window another worker extended
-                        st.wall_s = max(st.wall_s, t_done - t0)
-                        if counted is not None:
-                            st.accumulations += counted.accumulations
-                            st.fetched_bits += counted.fetched_bits
-                # registry mirrors (family-locked; outside the engine lock)
-                self._m_requests.inc(n_real)
-                self._m_batches.labels(engine=self.name,
-                                       backend=ver.backend).inc()
-                self._m_padded.inc(batch.n_padded)
-                self._m_qdepth.set(batch.queue_depth)
-                for r in batch.requests:
-                    self._m_latency.observe(t_done - r.t_enqueue)
-                    if r.trace is not None:
-                        # the jitted step is batch-wide: every traced rider
-                        # shares the same explicit start/end stamps
-                        r.trace.add("jit-step-start", t=t_step0,
-                                    version=ver.label, backend=ver.backend)
-                        r.trace.add("jit-step-end", t=t_step1)
-                for i, r in enumerate(batch.requests):
-                    # transitions PENDING -> RUNNING (after which cancel()
-                    # can no longer win the race); False = caller cancelled
-                    # while queued — skip, don't poison the batch
-                    if r.future.set_running_or_notify_cancel():
-                        tadd(r.trace, "complete", pred=int(preds[i]))
-                        tfinish(r.trace)
-                        r.future.set_result(int(preds[i]))
-                    else:
-                        tadd(r.trace, "cancelled", at="resolve")
-                        tfinish(r.trace)
+                    with span("engine.counters"):
+                        ver.activity.observe(
+                            {k: np.asarray(v) for k, v in accs.items()},
+                            n_real)
+                with span("engine.resolve"):
+                    # activity counting is an expensive diagnostics mode; it
+                    # runs outside the lock (workers stay parallel) but before
+                    # the futures resolve, so a caller that reads ``stats``
+                    # right after its results always sees them counted
+                    counted: Optional[ServeStats] = None
+                    if self.count_activity and ver.sparse is not None:
+                        counted = ServeStats()
+                        frames = sigma_delta_encode_np(
+                            batch.frames[:n_real], self.cfg.timesteps)
+                        count_batch_activity(counted, ver.sparse, frames,
+                                             self.cfg)
+                    # completion is stamped after counting: callers' futures
+                    # resolve after it, so latencies reflect what they waited
+                    t_done = time.perf_counter()
+                    with self._lock:
+                        # serving window: first enqueue ever -> latest batch
+                        # completion.  Correct for both the submit()/future
+                        # path and (possibly concurrent) classify() callers.
+                        # Each version additionally tracks its own window so a
+                        # late-bound canary's throughput is not diluted.
+                        batch_first = min(r.t_enqueue for r in batch.requests)
+                        self._t_first_enqueue = min(self._t_first_enqueue,
+                                                    batch_first)
+                        ver.t_first = min(ver.t_first, batch_first)
+                        for st, t0 in ((self.stats, self._t_first_enqueue),
+                                       (ver.stats, ver.t_first)):
+                            st.requests += n_real
+                            st.record_batch(ver.backend,
+                                            queue_depth=batch.queue_depth,
+                                            padded=batch.n_padded)
+                            st.record_latencies(
+                                t_done - r.t_enqueue for r in batch.requests)
+                            # max(): a worker delayed by activity counting must
+                            # not shrink a window another worker extended
+                            st.wall_s = max(st.wall_s, t_done - t0)
+                            if counted is not None:
+                                st.accumulations += counted.accumulations
+                                st.fetched_bits += counted.fetched_bits
+                    # registry mirrors (family-locked; outside the engine lock)
+                    self._m_requests.inc(n_real)
+                    self._m_batches.labels(engine=self.name,
+                                           backend=ver.backend).inc()
+                    self._m_padded.inc(batch.n_padded)
+                    self._m_qdepth.set(batch.queue_depth)
+                    for r in batch.requests:
+                        self._m_latency.observe(t_done - r.t_enqueue)
+                        if r.trace is not None:
+                            # the jitted step is batch-wide: every traced rider
+                            # shares the same explicit start/end stamps
+                            r.trace.add("jit-step-start", t=t_step0,
+                                        version=ver.label, backend=ver.backend)
+                            r.trace.add("jit-step-end", t=t_step1)
+                    for i, r in enumerate(batch.requests):
+                        # transitions PENDING -> RUNNING (after which cancel()
+                        # can no longer win the race); False = caller cancelled
+                        # while queued — skip, don't poison the batch
+                        if r.future.set_running_or_notify_cancel():
+                            tadd(r.trace, "complete", pred=int(preds[i]))
+                            tfinish(r.trace)
+                            r.future.set_result(int(preds[i]))
+                        else:
+                            tadd(r.trace, "cancelled", at="resolve")
+                            tfinish(r.trace)
             except Exception as e:  # noqa: BLE001 — propagate to callers;
                 # the whole batch path is covered so a stats/counting error
                 # can never strand a future or kill the worker loop

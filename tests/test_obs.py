@@ -44,7 +44,6 @@ from repro.obs import (
     static_schedule_counts,
 )
 from repro.plan import PlanCache
-from repro.plan.streaming import profile_layer_steps
 from repro.serve import AsyncAMCServeEngine, MicroBatcher
 from repro.train.pruning import make_mask_pytree
 
@@ -564,19 +563,3 @@ def test_metrics_server_endpoints():
         status, _, body = _get(srv.url("/trace"))
         assert status == 200
         assert json.loads(body)["n_completed"] == 1
-
-
-def test_profile_layer_steps_sets_gauges(weights):
-    params, masks = weights
-    program = compile_snn(CFG)
-    plan = compile_plan(program, params, masks=masks, assignment="stream",
-                        cache=PlanCache(disk_dir=""))
-    frames = jnp.zeros((CFG.timesteps, 2, CFG.input_width), jnp.float32)
-    ms = profile_layer_steps(plan, frames, reps=1)
-    assert set(ms) == {lp.spec.name for lp in plan.layers}
-    assert all(v > 0 for v in ms.values())
-    reg = default_registry()
-    backends = {lp.spec.name: lp.backend for lp in plan.layers}
-    for name, got_ms in ms.items():
-        assert reg.value("repro_plan_layer_step_ms", layer=name,
-                         backend=backends[name]) == got_ms
