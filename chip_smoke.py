@@ -207,13 +207,13 @@ def phase_a(params, masks, iq: np.ndarray, max_batch: int,
         log(f"[A] served {n} frames through submit() in "
             f"{time.perf_counter() - t0:.2f} s (smoke time, not a metric)")
 
-        step = engine.get_version(engine.active_version).step
+        ver = engine.get_version(engine.active_version)
         logits, accs = [], {}
         for sl in _chunks(n, max_batch):
-            lg, ac = step(jnp.asarray(iq[sl]))
-            logits.append(np.asarray(lg))
+            lg, ac = ver.unpack(np.asarray(ver.step(jnp.asarray(iq[sl]))))
+            logits.append(lg)
             for name, v in ac.items():
-                accs.setdefault(name, []).append(np.asarray(v))
+                accs.setdefault(name, []).append(v)
         logits = np.concatenate(logits)
         accs = {k: np.concatenate(v) for k, v in accs.items()}
         check(np.array_equal(preds, logits.argmax(-1)),

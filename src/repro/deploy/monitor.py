@@ -170,7 +170,8 @@ class CanaryMonitor:
         does not enter the request queue, so it never skews served
         latency stats)."""
         ver = self.engine.get_version(label)
-        return np.asarray(ver.step(jnp.asarray(iq))).argmax(-1)
+        logits, _ = ver.unpack(np.asarray(ver.step(jnp.asarray(iq))))
+        return logits.argmax(-1)
 
     def _score(self, preds: np.ndarray, labels: np.ndarray,
                ref: np.ndarray) -> float:

@@ -44,7 +44,7 @@ import dataclasses
 import threading
 import time
 from collections import Counter
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -55,7 +55,7 @@ from repro.core.cost_model import bits_fetched, fc_wm_counts, goap_conv_counts
 from repro.core.saocds import max_pool_spikes, pad_same, saocds_conv_layer
 from repro.core.sparse_format import weight_mask_from_dense
 from repro.data.pipeline import sigma_delta_encode_batch, sigma_delta_encode_np
-from repro.models.graph import compile_snn
+from repro.models.graph import KIND_CONV, compile_snn
 from repro.models.snn import SNNConfig, sparsify_params
 from repro.plan import compile_plan
 from repro.serve.autotune import (
@@ -323,10 +323,26 @@ class BoundVersion:
     # requests it served) — a late-bound canary's wall_s/throughput must
     # not be diluted by traffic that predates its bind
     t_first: float = float("inf")
-    # live-counter mode: the version's step returns (logits, per-conv
-    # accumulation counts) and this ActivityObserver records them; None
-    # means the step returns bare logits
+    # live-counter mode: the version's step returns one float32
+    # (B, n_classes + n_conv) array, the logits then each conv layer's
+    # accumulation counts in ``counter_names`` order, and this
+    # ActivityObserver records them; None means the step returns bare
+    # logits
     activity: Any = dataclasses.field(default=None, repr=False)
+    counter_names: Tuple[str, ...] = ()
+
+    def unpack(self, out: np.ndarray):
+        """Host copy of one step's output -> (logits, {conv: counts}).
+
+        The counts are None for a step without live counters; otherwise
+        the logits and every count column are views of ``out``.
+        """
+        if self.activity is None:
+            return out, None
+        n_classes = out.shape[-1] - len(self.counter_names)
+        return out[:, :n_classes], {
+            name: out[:, n_classes + i]
+            for i, name in enumerate(self.counter_names)}
 
 
 class AsyncAMCServeEngine:
@@ -406,6 +422,10 @@ class AsyncAMCServeEngine:
         self._m_batches = reg.counter(
             "repro_serve_batches_total", "Micro-batches served",
             ("engine", "backend"))
+        self._m_transfers = reg.counter(
+            "repro_serve_result_transfers_total",
+            "Device-to-host copies of a batch's results (one per batch)",
+            ("engine",)).labels(engine=eng)
         self._m_padded = reg.counter(
             "repro_serve_padded_frames_total",
             "Zero-padded tail rows shipped in fixed-shape buckets",
@@ -505,11 +525,11 @@ class AsyncAMCServeEngine:
             self._step = self._wrap_batch_fn(self.plan.preferred_batch(),
                                              int_encode=_uses_fixed(backend))
         self._activity: Optional[ActivityObserver] = None
+        counter_names: Tuple[str, ...] = ()
         if (counters_wanted and self.plan is not None
                 and self.plan.supports_live_counters):
-            self._step = self._wrap_batch_fn(
-                self.plan.batch_counters,
-                int_encode=_uses_fixed(self.assignment or backend))
+            self._step, counter_names = self._wrap_counters(
+                self.plan, int_encode=_uses_fixed(self.assignment or backend))
             self._activity = ActivityObserver(self.plan, engine=self.name)
 
         # readiness: armed by the first successful jitted step (warmup
@@ -530,7 +550,7 @@ class AsyncAMCServeEngine:
                 label=version_label, backend=self.backend, step=self._step,
                 plan=self.plan, sparse=self.sparse,
                 stats=ServeStats(backend=self.backend),
-                activity=self._activity),
+                activity=self._activity, counter_names=counter_names),
         }
         self._primary = version_label
         self._router: Optional[Callable[[], str]] = None
@@ -573,6 +593,38 @@ class AsyncAMCServeEngine:
             step = shard_serve_fn(step, self.mesh)
         return jax.jit(step)
 
+    def _wrap_counters(self, plan, int_encode: bool = False):
+        """The live-counter step, and the conv layer order of its counts.
+
+        Wraps ``plan.batch_counters`` so that the jit returns one float32
+        ``(B, n_classes + n_conv)`` array, the logits and then each conv
+        layer's per-frame accumulation counts, and a batch's results come
+        back in one device-to-host copy.  Counts are float32-exact below
+        2**24 per frame.  The logits are never cast: a plan whose logits
+        or counts are not float32 raises TypeError here, at bind time.
+        """
+        probe = jax.ShapeDtypeStruct(
+            (1, self.cfg.conv_specs[0][1], self.cfg.input_width),
+            jnp.float32)
+        logits, accs = jax.eval_shape(
+            self._wrap_batch_fn(plan.batch_counters, int_encode), probe)
+        dtypes = {"logits": logits.dtype,
+                  **{k: v.dtype for k, v in accs.items()}}
+        if any(d != jnp.float32 for d in dtypes.values()):
+            raise TypeError(
+                "live counters travel with the logits as one float32 "
+                f"array; the plan's step returns {dtypes}")
+
+        names = tuple(lp.spec.name for lp in plan.layers
+                      if lp.spec.kind == KIND_CONV and lp.spec.name in accs)
+
+        def packed(frames_b):
+            logits, accs = plan.batch_counters(frames_b)
+            return jnp.concatenate(
+                [logits, jnp.stack([accs[k] for k in names], -1)], -1)
+
+        return self._wrap_batch_fn(packed, int_encode), names
+
     def _wrap_bound(self, bound):
         return self._wrap_batch_fn(bound.batch,
                                    int_encode=_uses_fixed(bound.backend))
@@ -603,12 +655,14 @@ class AsyncAMCServeEngine:
         # one profiler span per phase of each batch, tiling the loop:
         # engine.gather (batcher.form inside it), engine.put,
         # engine.dispatch (to the step's asynchronous return), engine.fetch
-        # (the wait for the device and the copy back), engine.counters
-        # (live activity counters only), engine.resolve.  The input array
-        # is freed right after dispatch and a batch's outputs stay bound
-        # until the next batch rebinds them: freeing a device array
-        # releases the interpreter lock, which right after the futures
-        # resolve the client would take for its whole refill.
+        # (the wait for the device and the one copy back: a batch's logits
+        # and live counters arrive in a single array), engine.counters
+        # (live activity counters only; host-side accounting on that copy,
+        # no transfer), engine.resolve.  The input array is freed right
+        # after dispatch and a batch's outputs stay bound until the next
+        # batch rebinds them: freeing a device array releases the
+        # interpreter lock, which right after the futures resolve the
+        # client would take for its whole refill.
         while not self._stop.is_set():
             with span("engine.gather"):
                 batch = self.batcher.get_batch(timeout=0.1)
@@ -629,21 +683,17 @@ class AsyncAMCServeEngine:
                           n_real=batch.n_real, backend=ver.backend):
                     out = ver.step(x)
                     del x   # now, not at the next batch's put
-                if ver.activity is not None:
-                    logits_dev, accs = out
-                else:
-                    logits_dev, accs = out, None
-                with span("engine.fetch"):
-                    logits = np.asarray(logits_dev)
+                with span("engine.fetch", arrays=1):
+                    host = np.asarray(out)
                 t_step1 = time.perf_counter()
+                self._m_transfers.inc()
                 self._ready.set()  # first successful jit step: /readyz 200
+                logits, accs = ver.unpack(host)
                 preds = logits.argmax(-1).astype(np.int32)
                 n_real = batch.n_real
                 if accs is not None:
                     with span("engine.counters"):
-                        ver.activity.observe(
-                            {k: np.asarray(v) for k, v in accs.items()},
-                            n_real)
+                        ver.activity.observe(accs, n_real)
                 with span("engine.resolve"):
                     # activity counting is an expensive diagnostics mode; it
                     # runs outside the lock (workers stay parallel) but before
@@ -787,11 +837,12 @@ class AsyncAMCServeEngine:
                                        int_encode=_uses_fixed(backend))
         sparse = sparsify_params(params, masks) if self.count_activity else None
         activity = None
+        counter_names: Tuple[str, ...] = ()
         if (self.activity_gauges and self.mesh is None and plan is not None
                 and plan.supports_live_counters):
             enc = self.assignment if backend == "per-layer" else backend
-            step = self._wrap_batch_fn(plan.batch_counters,
-                                       int_encode=_uses_fixed(enc))
+            step, counter_names = self._wrap_counters(
+                plan, int_encode=_uses_fixed(enc))
             activity = ActivityObserver(plan, engine=self.name)
         if warmup:  # pre-compile every bucket so the flip never stalls
             ic0 = self.cfg.conv_specs[0][1]
@@ -802,7 +853,7 @@ class AsyncAMCServeEngine:
         ver = BoundVersion(label=label, backend=backend, step=step,
                            plan=plan, sparse=sparse,
                            stats=ServeStats(backend=backend),
-                           activity=activity)
+                           activity=activity, counter_names=counter_names)
         with self._lock:
             self._versions[label] = ver
         return ver

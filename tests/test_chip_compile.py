@@ -151,6 +151,23 @@ def test_serving_step_compiles(topo, one_chip, paper, backend):
         assert step.lower(x).compile().as_text()
 
 
+def test_pallas_fused_live_counter_step_compiles_on_one_chip(
+        topo, one_chip, paper, compiled_kernels):
+    """The one-chip serving step returns the logits and the conv layers'
+    counts packed in one float32 array, around the fused kernel."""
+    with _engine(paper, "pallas_fused") as engine:
+        ver = engine.get_version(engine.active_version)
+        assert ver.activity is not None
+        x = jax.ShapeDtypeStruct((64, IC0, W0), jnp.float32,
+                                 sharding=one_chip)
+        lowered = ver.step.lower(x)
+        out = lowered.out_info
+        assert out.shape == (64, CONFIG.n_classes + len(ver.counter_names))
+        assert out.dtype == jnp.float32
+        hlo = lowered.compile().as_text()
+        assert "tpu_custom_call" in hlo and "stream_fused" in hlo
+
+
 def test_pallas_fused_serving_step_compiles_over_four_chips(
         topo, paper, compiled_kernels):
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))

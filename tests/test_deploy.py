@@ -427,6 +427,29 @@ def test_monitor_rolls_back_injected_accuracy_regression(models):
         assert engine.classify(_iq(8)).shape == (8,)
 
 
+def test_monitor_scores_a_live_counter_version_on_its_logits(models):
+    """A version whose step also carries activity counters is scored on
+    its logits alone: against the engine's own served predictions, both
+    versions of the same weights score 1.0."""
+    p1, m1, _ = models
+    with AsyncAMCServeEngine(p1, CFG, masks=m1, backend="stream",
+                             max_batch=8, version_label="prod") as engine:
+        engine.bind_version("canary", p1, masks=m1)
+        assert all(v.activity is not None
+                   for v in engine.versions().values())
+
+        def source(seed, n, snr):
+            iq = _iq(n, seed=seed)
+            return iq, engine.classify(iq)
+
+        mon = CanaryMonitor(engine, baseline="prod", canary="canary",
+                            config=_monitor_cfg(score="labels"),
+                            frame_source=source)
+        res = mon.evaluate_round()
+    assert all(v == 1.0 for v in res.baseline_acc.values())
+    assert all(v == 1.0 for v in res.canary_acc.values())
+
+
 def test_monitor_rollback_in_labels_mode(models):
     """Same regression, scored against ground-truth labels: the frame
     source labels frames with production's own predictions (a replay

@@ -459,6 +459,117 @@ def test_engine_live_activity_gauges(weights):
         engine="live").count == 8
 
 
+def test_packed_step_carries_the_unpacked_results_bit_for_bit(weights):
+    """A live-counter step returns the logits and each conv layer's counts
+    as one float32 array: the same values the plan's counter step gives."""
+    from repro.data.pipeline import sigma_delta_encode_batch
+
+    params, masks = weights
+    eng = AsyncAMCServeEngine(params, CFG, masks=masks, backend="stream",
+                              buckets=[4], max_delay_ms=5, name="packed")
+    try:
+        ver = eng.get_version(eng.active_version)
+        assert ver.counter_names == ("conv1", "conv2")
+        iq = _iq(4, seed=7)
+        out = np.asarray(ver.step(jnp.asarray(iq)))
+        assert out.dtype == np.float32
+        assert out.shape == (4, CFG.n_classes + len(ver.counter_names))
+        frames = sigma_delta_encode_batch(jnp.asarray(iq), CFG.timesteps)
+        unpacked = jax.jit(eng._wrap_batch_fn(eng.plan.batch_counters))
+        for want_logits, want_accs in (eng.plan.batch_counters(frames),
+                                       unpacked(jnp.asarray(iq))):
+            np.testing.assert_array_equal(out[:, :CFG.n_classes],
+                                          np.asarray(want_logits))
+            for i, name in enumerate(ver.counter_names):
+                np.testing.assert_array_equal(out[:, CFG.n_classes + i],
+                                              np.asarray(want_accs[name]))
+        logits, accs = ver.unpack(out)
+        np.testing.assert_array_equal(logits, out[:, :CFG.n_classes])
+        assert list(accs) == list(ver.counter_names)
+    finally:
+        eng.close()
+
+
+def test_packed_step_refuses_logits_that_are_not_float32(weights):
+    """Logits are never cast to travel with the counts: a plan whose
+    counter step returns another dtype fails at bind time."""
+    import types
+
+    params, masks = weights
+    eng = AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                              buckets=[4], warmup=False)
+    try:
+        plan = compile_plan(compile_snn(CFG), params, masks=masks,
+                            assignment="stream",
+                            cache=PlanCache(disk_dir=""))
+
+        def half_logits(frames):
+            logits, accs = plan.batch_counters(frames)
+            return logits.astype(jnp.bfloat16), accs
+
+        fake = types.SimpleNamespace(layers=plan.layers,
+                                     batch_counters=half_logits)
+        with pytest.raises(TypeError, match="float32"):
+            eng._wrap_counters(fake)
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("backend, counters", [("dense", False),
+                                               ("stream", True)])
+def test_one_result_transfer_per_batch(weights, backend, counters):
+    params, masks = weights
+    eng = AsyncAMCServeEngine(params, CFG, masks=masks, backend=backend,
+                              buckets=[4], max_delay_ms=5, name="xfer")
+    try:
+        assert (eng.get_version(eng.active_version).activity is not None) \
+            == counters
+        eng.classify(_iq(10), timeout=60)
+        batches = eng.stats.batches
+    finally:
+        eng.close()
+    reg = default_registry()
+    served = sum(child.value for (engine, _backend), child in
+                 reg.get("repro_serve_batches_total").items()
+                 if engine == "xfer")
+    assert batches >= 3 and served == batches
+    assert reg.value("repro_serve_result_transfers_total",
+                     engine="xfer") == batches
+
+
+def test_swapped_version_feeds_the_same_activity_totals(weights):
+    """A version bound with ``bind_version`` and made primary counts the
+    same frames' activity exactly as the engine's first version did."""
+    params, masks = weights
+    eng = AsyncAMCServeEngine(params, CFG, masks=masks, backend="stream",
+                              buckets=[4], max_delay_ms=5, name="swap")
+    reg = default_registry()
+    layers = ("conv1", "conv2")
+
+    def totals():
+        return {layer: reg.value("repro_activity_accumulations_total",
+                                 engine="swap", layer=layer)
+                for layer in layers}
+
+    try:
+        iq = _iq(8, seed=3)
+        first_preds = eng.classify(iq, timeout=60)
+        first = totals()
+        ver = eng.bind_version("v2", params, masks=masks)
+        assert ver.activity is not None
+        assert ver.counter_names == layers
+        assert eng.swap_to("v2") == "default"
+        second_preds = eng.classify(iq, timeout=60)
+        second = totals()
+    finally:
+        eng.close()
+    np.testing.assert_array_equal(first_preds, second_preds)
+    assert all(first[layer] > 0 for layer in layers)
+    assert {k: second[k] - first[k] for k in layers} == first
+    assert reg.value("repro_activity_frames_total", engine="swap") == 16
+    assert eng.version_stats()["v2"].requests == 8
+
+
 # ---------------------------------------------------------------------------
 # control-plane metric emission: autoscaler / canary / swap
 # ---------------------------------------------------------------------------
