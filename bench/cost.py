@@ -3,10 +3,12 @@
 Work is 2 operations per multiply-accumulate over the **non-zero** weights
 at every output position and timestep, plus 4 operations per LIF
 neuron-step (decay, accumulate, compare, reset) of every layer whose
-spikes feed the output.  Encoder, pooling and the readout sum are left
-out: together they are under 1% of the dense count.  The count is the
-same whatever implements the network, so a kernel that skips zero weights
-or silent inputs is credited in time and never in work.
+spikes feed the output, plus 1 per element and timestep of a residual
+shortcut's add; shapes from :func:`network.layers`.  Encoder, pooling and
+the readout sum are left out: together they are under 1% of the dense
+count.  The count is the same whatever implements the network, so a
+kernel that skips zero weights or silent inputs is credited in time and
+never in work.
 
 Bytes are what one call of the serving step must move at least: the
 stored weights and LIF constants once per call, and per frame the I/Q
@@ -18,16 +20,11 @@ from typing import Dict, Mapping, Optional
 
 import numpy as np
 
+import network
+
 LIF_OPS = 4
+SHORTCUT_OPS = 1
 LIF_CONSTS = 3      # alpha, theta, v_th per neuron (or per channel)
-
-
-def _conv_widths(net: Mapping) -> list:
-    widths, w = [], int(net["input_width"])
-    for _ in net["conv_specs"]:
-        widths.append(w)
-        w //= int(net["pool"])
-    return widths
 
 
 def layer_work(net: Mapping, nonzero: Optional[Mapping[str, int]] = None
@@ -35,25 +32,26 @@ def layer_work(net: Mapping, nonzero: Optional[Mapping[str, int]] = None
     """Operations per frame by layer (``<layer>`` MACs, ``lif`` neurons).
 
     ``nonzero`` maps ``conv1``... ``fcN`` to the number of non-zero
-    weights; a missing layer counts dense.
+    weights; a missing layer counts dense.  A network with residual
+    stages also has ``shortcut``, its adds.
     """
     nonzero = nonzero or {}
     t = int(net["timesteps"])
     readout = net.get("readout", "current_sum")
+    layers, ops = network.layers(net)
+    last = len(layers) - 1
     out: Dict[str, float] = {}
     lif_neurons = 0
-    for i, ((kw, ic, oc), width) in enumerate(zip(net["conv_specs"],
-                                                  _conv_widths(net))):
-        name = f"conv{i + 1}"
-        out[name] = 2.0 * nonzero.get(name, kw * ic * oc) * width * t
-        lif_neurons += oc * width
-    n_fc = len(net["fc_specs"])
-    for i, (din, dout) in enumerate(net["fc_specs"]):
-        name = f"fc{i + 1}"
-        out[name] = 2.0 * nonzero.get(name, din * dout) * t
-        if i < n_fc - 1 or readout != "current_sum":
-            lif_neurons += dout
+    for i, layer in enumerate(layers):
+        out[layer.name] = (2.0 * nonzero.get(layer.name, layer.n_weights)
+                           * layer.width * t)
+        if i < last or readout != "current_sum":
+            lif_neurons += layer.c_out * layer.width
     out["lif"] = float(LIF_OPS * lif_neurons * t)
+    adds = sum(layers[op.layer].c_out * layers[op.layer].width
+               for op in ops if op.shortcut)
+    if adds:
+        out["shortcut"] = float(SHORTCUT_OPS * adds * t)
     return out
 
 
@@ -73,16 +71,15 @@ def nonzero_counts(weights: Mapping) -> Dict[str, int]:
 
 def weight_bytes(net: Mapping, bytes_per_weight: int = 4) -> int:
     """Stored weights (dense, as the step holds them) plus LIF constants."""
-    n = sum(kw * ic * oc for kw, ic, oc in net["conv_specs"])
-    n += sum(din * dout for din, dout in net["fc_specs"])
-    consts = sum(oc for _, _, oc in net["conv_specs"])
-    consts += sum(dout for _, dout in net["fc_specs"])
+    layers, _ = network.layers(net)
+    n = sum(layer.n_weights for layer in layers)
+    consts = sum(layer.c_out for layer in layers)
     return int(n * bytes_per_weight + LIF_CONSTS * consts * 4)
 
 
 def frame_bytes(net: Mapping, n_counters: int = 0) -> int:
     """Per frame: float32 I/Q in, int32/float32 logits and counters out."""
-    return int(4 * (int(net["conv_specs"][0][1]) * int(net["input_width"])
+    return int(4 * (network.input_channels(net) * int(net["input_width"])
                     + int(net["n_classes"]) + n_counters))
 
 

@@ -3,17 +3,19 @@
 Two references, one per kind of configuration:
 
 * :func:`float_reference` runs the float network (Σ-Δ encoder, conv + LIF
-  + max-pool stages, FC + LIF, current-sum or spike-count readout) in
-  float64 with NumPy.  Besides the logits it returns, per frame, the
-  least distance of any decision the network takes from its threshold: the
-  encoder's comparator, every LIF membrane whose spikes feed the output,
-  and the gap between the two largest logits.  A float32 path that sums in
-  another order may decide differently only where that distance is within
-  float32 rounding, so such a frame is a tie and not an error.
+  + max-pool stages or residual stages, FC + LIF, current-sum or
+  spike-count readout; the ops of :func:`network.layers`) in float64 with
+  NumPy.  Besides the logits it returns, per frame, the least distance of
+  any decision the network takes from its threshold: the encoder's
+  comparator, every LIF membrane whose spikes feed the output, and the gap
+  between the two largest logits.  A float32 path that sums in another
+  order may decide differently only where that distance is within float32
+  rounding, so such a frame is a tie and not an error.
 * :func:`integer_reference` runs the integer twin: weights quantised to
   ``bits`` per layer by max-abs calibration, a Q0.15 Σ-Δ front end, int32
   gated accumulation, shift leak, strict threshold, soft reset and a
-  saturating int16 membrane.  Its logits are exact integers.
+  saturating int16 membrane.  Its logits are exact integers.  It takes
+  chains only.
 
 ``weights`` is the pytree of :func:`weights.make_weights` as NumPy arrays;
 ``net`` the configuration's ``network`` block.  Frames are processed in
@@ -24,6 +26,8 @@ from __future__ import annotations
 from typing import Callable, Tuple
 
 import numpy as np
+
+import network
 
 BLOCK = 256
 ENC_ONE = 1 << 15
@@ -98,8 +102,7 @@ def _float_layers(weights: dict, weight_map: Callable, xp, dtype):
         masked = np.asarray(layer["w"]) * np.asarray(layer["mask"])
         return xp.asarray(weight_map(masked), dtype)
 
-    return ([(w(l), lif(l)) for l in weights["conv"]],
-            [(w(l), lif(l)) for l in weights["fc"]])
+    return [(w(l), lif(l)) for l in weights["conv"] + weights["fc"]]
 
 
 def _lif(v, cur, params, margin, n, xp, dtype):
@@ -110,30 +113,36 @@ def _lif(v, cur, params, margin, n, xp, dtype):
     return v - theta * s, s, margin
 
 
-def _float_block(iq, net, convs, fcs, dot, xp, dtype):
+def _float_block(iq, net, layers, ops, dot, xp, dtype):
     n = iq.shape[0]
     spikes, margin = encode_float(iq, net["timesteps"], dtype)
     spikes, margin = xp.asarray(spikes), xp.asarray(margin, dtype)
     readout = net.get("readout", "current_sum")
-    v_conv = [0.0] * len(convs)
-    v_fc = [0.0] * len(fcs)
+    last = len(layers) - 1
+    v = [0.0] * len(layers)
     logits = 0.0
     for t in range(net["timesteps"]):
         x = spikes[:, t]
-        for i, (w, params) in enumerate(convs):
-            v_conv[i], s, margin = _lif(v_conv[i], _conv(x, w, dot, xp),
-                                        params, margin, n, xp, dtype)
-            x = _pool(s, net["pool"])
-        x = _flatten(x, xp)
-        for i, (w, params) in enumerate(fcs):
-            cur = dot(x, w)
-            last = i == len(fcs) - 1
-            if last and readout == "current_sum":
+        for op in ops:
+            if op.kind == "skip":
+                skip = x
+                continue
+            if op.kind == "pool":
+                x = _pool(x, op.size)
+                continue
+            if op.kind == "flatten":
+                x = _flatten(x, xp)
+                continue
+            w, params = layers[op.layer]
+            cur = _conv(x, w, dot, xp) if op.kind == "conv" else dot(x, w)
+            if op.shortcut:
+                cur = cur + skip
+            if op.layer == last and readout == "current_sum":
                 logits = logits + cur   # this layer's spikes feed nothing
                 continue
-            v_fc[i], x, margin = _lif(v_fc[i], cur, params, margin, n, xp,
-                                      dtype)
-            if last:
+            v[op.layer], x, margin = _lif(v[op.layer], cur, params, margin,
+                                          n, xp, dtype)
+            if op.layer == last:
                 logits = logits + x
     top2 = xp.sort(logits, axis=1)[:, -2:]
     margin = xp.minimum(margin, top2[:, 1] - top2[:, 0])
@@ -147,10 +156,13 @@ def float_reference(iq: np.ndarray, weights: dict, net: dict,
 
     By default in float64 with NumPy.  ``weight_map``, ``dot``, ``xp`` and
     ``dtype`` exist for the control, which runs this same network at a
-    lower precision (for instance with ``xp=jax.numpy`` on a chip).
+    lower precision (for instance with ``xp=jax.numpy`` on a chip).  Every
+    LIF feeds the margin: in a residual stage the projection's and both
+    of each unit's.
     """
-    convs, fcs = _float_layers(weights, weight_map, xp, dtype)
-    parts = [_float_block(iq[s:s + BLOCK], net, convs, fcs, dot, xp, dtype)
+    layers = _float_layers(weights, weight_map, xp, dtype)
+    _, ops = network.layers(net)
+    parts = [_float_block(iq[s:s + BLOCK], net, layers, ops, dot, xp, dtype)
              for s in range(0, iq.shape[0], BLOCK)]
     return (np.concatenate([p[0] for p in parts]),
             np.concatenate([p[1] for p in parts]))
@@ -234,6 +246,9 @@ def _int_lif(v16, cur, consts):
 def integer_reference(iq: np.ndarray, weights: dict, net: dict,
                       bits: int) -> np.ndarray:
     """Exact integer logits (N, classes) of the integer twin at ``bits``."""
+    if not network.is_chain(net):
+        raise ValueError("integer_reference: there is no integer twin of a "
+                         "network with residual stages; a chain only")
     def layer(l):
         codes, step = quantize_layer(np.asarray(l["w"]) * np.asarray(l["mask"]),
                                      bits)

@@ -134,17 +134,38 @@ def peak_for(cfg: dict, kind: str, rehearse: bool) -> dict:
             "bytes_per_s": float(peaks[kind]["hbm_bytes_per_s"])}
 
 
+def _int_tuples(specs) -> tuple:
+    return tuple(tuple(int(v) for v in s) for s in specs)
+
+
+# the network keys the program has always taken, cast as it takes them
+CHAIN_CASTS = {"conv_specs": _int_tuples, "pool": int, "fc_specs": _int_tuples,
+               "input_width": int, "timesteps": int, "n_classes": int,
+               "readout": str, "lif_alpha": float, "lif_theta": float,
+               "lif_v_th": float}
+
+
 def snn_config(net: dict):
+    """The program's ``SNNConfig`` for a network; Refused where it has none.
+
+    Keys beyond the chain's go as nested tuples in the field order of
+    ``bench/network.py`` (``stages`` as ``((channels, proj_kw, units,
+    kw, pool), ...)``).
+    """
+    import dataclasses
+
+    import network
     from repro.models.snn import SNNConfig
 
-    return SNNConfig(
-        conv_specs=tuple(tuple(int(v) for v in s) for s in net["conv_specs"]),
-        pool=int(net["pool"]),
-        fc_specs=tuple(tuple(int(v) for v in s) for s in net["fc_specs"]),
-        input_width=int(net["input_width"]), timesteps=int(net["timesteps"]),
-        n_classes=int(net["n_classes"]), readout=net["readout"],
-        lif_alpha=float(net["lif_alpha"]), lif_theta=float(net["lif_theta"]),
-        lif_v_th=float(net["lif_v_th"]))
+    kwargs = {k: CHAIN_CASTS[k](v) if k in CHAIN_CASTS
+              else network.program_value(k, v) for k, v in net.items()}
+    try:
+        return SNNConfig(**kwargs)
+    except TypeError as e:
+        fields = {f.name for f in dataclasses.fields(SNNConfig)}
+        lacking = ", ".join(sorted(set(kwargs) - fields)) or "?"
+        raise Refused(f"bench: the program cannot run this network: its "
+                      f"SNNConfig has no field {lacking} ({e})") from None
 
 
 def make_pool(seed: int, cfg: dict, traffic: dict):
@@ -155,7 +176,9 @@ def make_pool(seed: int, cfg: dict, traffic: dict):
     lo, hi, step = spec["snr_db"]
     iq, labels, _ = frames.frame_pool(seed, int(spec["pool"]),
                                       np.arange(lo, hi + step / 2, step),
-                                      frame_len=int(cfg["network"]["input_width"]))
+                                      frame_len=int(cfg["network"]["input_width"]),
+                                      class_set=spec.get("classes",
+                                                         "radioml2016"))
     return iq, labels
 
 

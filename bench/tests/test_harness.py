@@ -8,6 +8,8 @@
   exchange between chips left out.
 * With no TPU and no ``--rehearse`` the harness prints no result, and it
   prints none in a checkout that holds only the benchmark.
+* A network of residual stages, which the program cannot run yet, is
+  refused (rc 2) with the ``SNNConfig`` fields it lacks named.
 
 Runs the harness with ``--rehearse`` at test sizes (``data/tiny-*.json``).
 """
@@ -31,6 +33,9 @@ NEW_CELLS = [
      "traffic": "tiny-closed", "chips": 1, "why": "test size, integer twin"},
     {"name": "tiny-f32.closed.x4", "config": "tiny-f32",
      "traffic": "tiny-closed.x4", "chips": 4, "why": "test size, 4 devices"},
+    {"name": "tiny-resnet.closed", "config": "tiny-resnet",
+     "traffic": "tiny-closed", "chips": 1,
+     "why": "test size, residual stages"},
 ]
 NEW_METRIC = {"name": "answers_per_s", "unit": "1/s", "better": "higher",
               "bound": 0.25, "source": "host_clock",
@@ -83,7 +88,7 @@ def checkout(tmp_path_factory):
     root = tmp_path_factory.mktemp("checkout")
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    for name in ("tiny-f32.json", "tiny-int8.json"):
+    for name in ("tiny-f32.json", "tiny-int8.json", "tiny-resnet.json"):
         shutil.copy(os.path.join(DATA, name), root / "bench" / "configs")
     shutil.copy(os.path.join(DATA, "tiny-closed.json"),
                 root / "bench" / "traffic")
@@ -144,6 +149,13 @@ def test_a_broken_timed_path_is_not_correct(checkout, cell, fault, devices):
     if fault != "none":
         assert out["check"]["wrong_share"]["value"] > \
             out["check"]["wrong_share"]["limit"]
+
+
+def test_a_stage_network_is_refused_naming_the_missing_field(checkout):
+    rc, out, proc = _run(checkout, "tiny-resnet.closed")
+    assert rc == 2 and out is None, proc.stderr[-2000:]
+    assert "Traceback" not in proc.stderr
+    assert "SNNConfig has no field input_channels, stages" in proc.stderr
 
 
 def test_no_tpu_no_result(checkout):

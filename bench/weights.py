@@ -15,7 +15,9 @@ array of the network in float32, the type the engine serves:
 * for each layer a magnitude mask keeping the ``round(n * density)``
   largest ``|w|``.
 
-The same arrays feed the system under test and the plain reference.
+Shapes and fan-in come from :func:`network.layers`, and the fit's
+forward pass walks its ops, so chains and residual stages alike.  The
+same arrays feed the system under test and the plain reference.
 """
 from __future__ import annotations
 
@@ -28,14 +30,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+import network
 import reference
 
 RIDGE = 1e-3     # ridge strength, as a share of the mean feature energy
 
 
 def layer_names(net: dict) -> List[str]:
-    return ([f"conv{i + 1}" for i in range(len(net["conv_specs"]))]
-            + [f"fc{i + 1}" for i in range(len(net["fc_specs"]))])
+    return [layer.name for layer in network.layers(net)[0]]
 
 
 def densities(cfg: dict) -> Dict[str, float]:
@@ -77,23 +79,33 @@ def _encode(iq, timesteps: int):
 def _readout_inputs(iq, layers, net):
     """Spike counts (N, D) at the input of the last FC layer."""
     dot = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
-    n_conv = len(net["conv_specs"])
-    v = [0.0] * (len(layers) - 1)
+    _, ops = network.layers(net)
+    last = len(layers) - 1
+    v = [0.0] * last
     counts = 0.0
     for x in _encode(iq, int(net["timesteps"])):
-        for i, layer in enumerate(layers[:-1]):
-            w = layer["w"] * layer["mask"]
-            alpha = jax.nn.sigmoid(layer["alpha_logit"]).reshape(-1)
-            if i < n_conv:
-                cur = reference._conv(x, w, dot, jnp)
-            else:
-                cur = dot(x, w)
-            v[i] = alpha * v[i] + cur
-            s = (v[i] > layer["v_th"].reshape(-1)).astype(jnp.float32)
-            v[i] = v[i] - layer["theta"].reshape(-1) * s
-            x = reference._pool(s, int(net["pool"])) if i < n_conv else s
-            if i == n_conv - 1:
+        for op in ops:
+            if op.kind == "skip":
+                skip = x
+            elif op.kind == "pool":
+                x = reference._pool(x, op.size)
+            elif op.kind == "flatten":
                 x = reference._flatten(x, jnp)
+            elif op.layer < last:
+                i = op.layer
+                layer = layers[i]
+                w = layer["w"] * layer["mask"]
+                alpha = jax.nn.sigmoid(layer["alpha_logit"]).reshape(-1)
+                if op.kind == "conv":
+                    cur = reference._conv(x, w, dot, jnp)
+                else:
+                    cur = dot(x, w)
+                if op.shortcut:
+                    cur = cur + skip
+                v[i] = alpha * v[i] + cur
+                s = (v[i] > layer["v_th"].reshape(-1)).astype(jnp.float32)
+                v[i] = v[i] - layer["theta"].reshape(-1) * s
+                x = s
         counts = counts + x
     return counts
 
@@ -110,11 +122,12 @@ def make_weights(seed: int, cfg: dict, fit_iq: np.ndarray,
     dens = densities(cfg)
     alpha = float(net["lif_alpha"])
     logit = math.log(alpha / (1.0 - alpha))
-    names = layer_names(net)
-    shapes = ([("conv", (kw, ic, oc), kw * ic, (oc, 1))
-               for kw, ic, oc in net["conv_specs"]]
-              + [("fc", (din, dout), din, (dout,))
-                 for din, dout in net["fc_specs"]])
+    specs, _ = network.layers(net)
+    names = [layer.name for layer in specs]
+    shapes = [("conv", (l.kw, l.c_in, l.c_out), l.kw * l.c_in, (l.c_out, 1))
+              if l.kind == "conv" else
+              ("fc", (l.c_in, l.c_out), l.c_in, (l.c_out,)) for l in specs]
+    n_conv = sum(l.kind == "conv" for l in specs)
     n_classes = int(net["n_classes"])
 
     @jax.jit
@@ -140,7 +153,6 @@ def make_weights(seed: int, cfg: dict, fit_iq: np.ndarray,
             jnp.matmul(a.T, y, precision=jax.lax.Precision.HIGHEST))
         layers[-1]["w"] = w_out
         layers[-1]["mask"] = _mask(w_out, dens[names[-1]])
-        n_conv = len(net["conv_specs"])
         return {"conv": layers[:n_conv], "fc": layers[n_conv:]}
 
     return gen(seed_key(seed), jnp.asarray(fit_iq, jnp.float32),
