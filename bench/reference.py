@@ -19,13 +19,21 @@ Two references, one per kind of configuration:
 
 ``weights`` is the pytree of :func:`weights.make_weights` as NumPy arrays;
 ``net`` the configuration's ``network`` block.  Frames are processed in
-blocks so that memory stays small.
+blocks so that memory stays small.  Frames are independent, so the
+float reference in NumPy runs its blocks on a pool of threads (NumPy's
+matmuls and elementwise passes release the interpreter lock), with the
+BLAS held to one thread each.  Each frame goes through the operations of
+one serial pass; ``bench/tests/test_network.py`` holds the results to
+the bits a serial pass gave.
 """
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Tuple
 
 import numpy as np
+from threadpoolctl import threadpool_limits
 
 import network
 
@@ -108,8 +116,11 @@ def _float_layers(weights: dict, weight_map: Callable, xp, dtype):
 def _lif(v, cur, params, margin, n, xp, dtype):
     alpha, theta, v_th = params
     v = alpha * v + cur
-    margin = xp.minimum(margin, xp.abs(v - v_th).reshape(n, -1).min(axis=1))
-    s = (v > v_th).astype(dtype)
+    # v > v_th exactly where v - v_th > 0: with gradual underflow the
+    # difference of two unequal floats is never 0 and keeps their order
+    d = v - v_th
+    margin = xp.minimum(margin, xp.abs(d).reshape(n, -1).min(axis=1))
+    s = (d > 0).astype(dtype)
     return v - theta * s, s, margin
 
 
@@ -158,12 +169,28 @@ def float_reference(iq: np.ndarray, weights: dict, net: dict,
     ``dtype`` exist for the control, which runs this same network at a
     lower precision (for instance with ``xp=jax.numpy`` on a chip).  Every
     LIF feeds the margin: in a residual stage the projection's and both
-    of each unit's.
+    of each unit's.  With ``xp`` NumPy the frames are cut into one block
+    per CPU (more where a block would pass :data:`BLOCK` frames), each
+    run on a thread; any other ``xp`` runs blocks of :data:`BLOCK` frames
+    one after another.
     """
     layers = _float_layers(weights, weight_map, xp, dtype)
     _, ops = network.layers(net)
-    parts = [_float_block(iq[s:s + BLOCK], net, layers, ops, dot, xp, dtype)
-             for s in range(0, iq.shape[0], BLOCK)]
+    n = iq.shape[0]
+    cpus = os.cpu_count() or 1
+    size = max(1, min(BLOCK, -(-n // cpus))) if xp is np else BLOCK
+
+    def block(start):
+        return _float_block(iq[start:start + size], net, layers, ops, dot,
+                            xp, dtype)
+
+    starts = range(0, n, size)
+    if xp is np:
+        with threadpool_limits(limits=1, user_api="blas"), \
+                ThreadPoolExecutor(max(1, min(cpus, len(starts)))) as pool:
+            parts = list(pool.map(block, starts))
+    else:
+        parts = [block(s) for s in starts]
     return (np.concatenate([p[0] for p in parts]),
             np.concatenate([p[1] for p in parts]))
 

@@ -3,13 +3,19 @@
 * The existing configurations read as before: sha256 digests of their
   weights and masks, frame pools, reference outputs and cost numbers,
   recorded in ``data/chain_digests.json`` before the stage form existed,
-  must hold.  Regenerate only for a deliberate change of those numbers:
-  ``python bench/tests/test_network.py``.
+  must hold.  So must the float64 reference's logits and margins of two
+  stage networks, ``tiny-resnet`` and the published O'Shea ResNet,
+  recorded in ``data/stage_digests.json`` before the reference was made
+  to run its blocks on threads.  Regenerate only for a deliberate change
+  of those numbers: ``python bench/tests/test_network.py``.
 * A chain expands to the names, shapes and order it always had.
 * A residual stage does what ``bench/network.py`` says: the shortcut
   alone, checked by hand; float64 NumPy and float32 ``jax.numpy`` agree
   on every frame judged; the weights' fitting pass and the reference run
   the same network; the cost model counts the published ResNet's work.
+* ``bench/run.py`` gives the program a stage network's fields where its
+  ``SNNConfig`` has them, and is refused naming them where it has not
+  (held with a chain-only stand-in, whatever the program has).
 * The RadioML 2018.01A class set has its 24 classes.
 """
 import hashlib
@@ -23,8 +29,11 @@ import pytest
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DATA = os.path.join(BENCH, "tests", "data")
 DIGESTS = os.path.join(DATA, "chain_digests.json")
+STAGE_DIGESTS = os.path.join(DATA, "stage_digests.json")
 CHAIN_CONFIGS = ["snn-amc-f32-d50", "snn-amc-int8-d50"]
 DIGEST_SEEDS = [11, 2 ** 31 + 5]
+OSHEA_SEEDS = [12, 13]
+OSHEA_FRAMES = 8
 POOL = 64
 SNR_GRID = np.arange(-20, 19, 2)
 
@@ -48,6 +57,8 @@ OSHEA_RESNET = {
     "readout": "current_sum", "lif_alpha": 0.9, "lif_theta": 1.0,
     "lif_v_th": 1.0,
 }
+# the LIF constants at which every stage of it stays active (PERF.md 4.1)
+OSHEA_ACTIVE = dict(OSHEA_RESNET, lif_theta=0.7, lif_v_th=0.7)
 
 
 def _cfg(name):
@@ -99,6 +110,37 @@ def test_existing_configurations_read_bit_identically(name, seed):
     with open(DIGESTS) as f:
         recorded = json.load(f)[f"{name}/{seed}"]
     assert chain_digests(name, seed) == recorded
+
+
+def stage_digest(name: str, seed: int) -> str:
+    """Digest of the float64 reference's logits and margins of a stage net.
+
+    ``tiny-resnet`` on its 64-frame test pool; ``oshea`` (the published
+    widths, v_th = theta = 0.7) on 8 RadioML 2018.01A frames that its
+    readout is also fitted on.
+    """
+    if name == "tiny-resnet":
+        _, net, iq, w = _tiny_resnet_setup(seed)
+    else:
+        net = OSHEA_ACTIVE
+        iq, labels, _ = frames.frame_pool(seed, OSHEA_FRAMES,
+                                          np.arange(-20, 31, 2),
+                                          frame_len=int(net["input_width"]),
+                                          class_set="radioml2018")
+        w = weights_mod.to_host(weights_mod.make_weights(
+            seed, {"network": net, "density": 0.5}, iq, labels))
+    return _digest(*reference.float_reference(iq, w, net))
+
+
+STAGE_CASES = [("tiny-resnet", s) for s in DIGEST_SEEDS] + \
+    [("oshea", s) for s in OSHEA_SEEDS]
+
+
+@pytest.mark.parametrize("name,seed", STAGE_CASES)
+def test_stage_reference_reads_bit_identically(name, seed):
+    with open(STAGE_DIGESTS) as f:
+        recorded = json.load(f)[f"{name}/{seed}"]
+    assert stage_digest(name, seed) == recorded
 
 
 def test_chain_expands_to_the_layers_it_always_had():
@@ -241,18 +283,41 @@ def test_stage_network_has_no_integer_twin():
         reference.integer_reference(iq, w, net, 8)
 
 
-def test_program_config_fields_of_a_stage_network():
+def test_program_config_fields_of_a_stage_network(stage_fields):
     import run as bench_run
 
     net = _tiny_resnet()["network"]
-    assert network.program_value("stages", net["stages"]) == (
-        (4, 1, 1, 3, 2), (8, 1, 2, 3, 2))
-    with pytest.raises(bench_run.Refused, match="no field input_channels, "
-                                                "stages"):
-        bench_run.snn_config(net)
+    stages = ((4, 1, 1, 3, 2), (8, 1, 2, 3, 2))
+    assert network.program_value("stages", net["stages"]) == stages
+    if stage_fields:
+        cfg = bench_run.snn_config(net)
+        assert cfg.stages == stages and cfg.input_channels == 2
+    else:
+        with pytest.raises(bench_run.Refused, match="no field input_channels, "
+                                                    "stages"):
+            bench_run.snn_config(net)
     chain = _cfg("snn-amc-f32-d50")["network"]
     from repro.models.snn import SNNConfig
     assert bench_run.snn_config(chain) == SNNConfig()
+
+
+def test_a_chain_only_program_refuses_a_stage_network(monkeypatch):
+    """The refusal path, with a stand-in that has the chain's fields only."""
+    import dataclasses
+
+    import repro.models.snn as snn
+    import run as bench_run
+
+    chain_only = dataclasses.make_dataclass(
+        "SNNConfig", [(k, object, None) for k in bench_run.CHAIN_CASTS],
+        frozen=True)
+    monkeypatch.setattr(snn, "SNNConfig", chain_only)
+    with pytest.raises(bench_run.Refused, match="no field input_channels, "
+                                                "stages"):
+        bench_run.snn_config(_tiny_resnet()["network"])
+    chain = _cfg("snn-amc-f32-d50")["network"]
+    assert bench_run.snn_config(chain).conv_specs == \
+        tuple(tuple(s) for s in chain["conv_specs"])
 
 
 def test_cost_of_the_published_residual_network():
@@ -286,9 +351,12 @@ def test_radioml2018_class_set():
 
 
 if __name__ == "__main__":
-    table = {f"{n}/{s}": chain_digests(n, s)
-             for n in CHAIN_CONFIGS for s in DIGEST_SEEDS}
-    with open(DIGESTS, "w") as f:
-        json.dump(table, f, indent=1, sort_keys=True)
-        f.write("\n")
-    print(json.dumps(table, indent=1))
+    for path, table in (
+            (DIGESTS, {f"{n}/{s}": chain_digests(n, s)
+                       for n in CHAIN_CONFIGS for s in DIGEST_SEEDS}),
+            (STAGE_DIGESTS, {f"{n}/{s}": stage_digest(n, s)
+                             for n, s in STAGE_CASES})):
+        with open(path, "w") as f:
+            json.dump(table, f, indent=1, sort_keys=True)
+            f.write("\n")
+        print(json.dumps(table, indent=1))
