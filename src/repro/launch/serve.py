@@ -80,7 +80,10 @@ def main(argv=None) -> int:
                          "fixed/LSQ serving paths (default: the registry "
                          "version's setting, else 16)")
     ap.add_argument("--max-delay-ms", type=float, default=5.0)
-    ap.add_argument("--workers", type=int, default=1)
+    ap.add_argument("--workers", type=int, default=None,
+                    help="serving threads per engine (batches in flight); "
+                         "default: the engine's own, two on an accelerator "
+                         "and one on the CPU")
     ap.add_argument("--replicas", type=int, default=1,
                     help="saocds-amc: replica groups behind a fleet router "
                          "with join-shortest-queue dispatch and admission "

@@ -27,6 +27,7 @@ config (asserted in ``tests/test_obs.py``).
 """
 from __future__ import annotations
 
+import threading
 from typing import Dict, List, Mapping, Optional
 
 import numpy as np
@@ -108,6 +109,9 @@ class ActivityObserver:
         self.engine = engine
         self.geometry = _conv_geometry(plan)
         self.timesteps = int(plan.cfg.timesteps)
+        # serving threads observe concurrently: one batch's last-batch
+        # gauges are set together, never interleaved with another's
+        self._lock = threading.Lock()
 
         sched = reg.gauge(
             "repro_activity_schedule",
@@ -163,17 +167,18 @@ class ActivityObserver:
         """
         if n_real <= 0:
             return
-        self._frames.inc(n_real)
-        for name, handles in self._per_layer.items():
-            acc = accumulations.get(name)
-            if acc is None:
-                continue
-            # sum in float64: a batch total passes 2**24 (the float32
-            # exactness limit) on the paper config at batch 64
-            total = float(np.asarray(acc)[:n_real].sum(dtype=np.float64))
-            per_frame = total / n_real
-            g = handles["geom"]
-            handles["acc"].inc(total)
-            handles["epf"].set(per_frame)
-            handles["ratio"].set(per_frame / g["dense_macs_per_frame"])
-            handles["density"].set(per_frame / g["sparse_macs_per_frame"])
+        # sum in float64: a batch total passes 2**24 (the float32
+        # exactness limit) on the paper config at batch 64
+        totals = {name: float(np.asarray(accumulations[name])[:n_real]
+                              .sum(dtype=np.float64))
+                  for name in self._per_layer if name in accumulations}
+        with self._lock:
+            self._frames.inc(n_real)
+            for name, total in totals.items():
+                handles = self._per_layer[name]
+                per_frame = total / n_real
+                g = handles["geom"]
+                handles["acc"].inc(total)
+                handles["epf"].set(per_frame)
+                handles["ratio"].set(per_frame / g["dense_macs_per_frame"])
+                handles["density"].set(per_frame / g["sparse_macs_per_frame"])

@@ -20,6 +20,12 @@ Flush policy (the standard dynamic-batching trade-off):
   as the per-replica service-rate cap; the batch keeps filling while the
   gate holds, so pacing *improves* batching efficiency under load).
 
+**One batch forms at a time.**  Several consumers (the engine's serving
+threads) take turns: a consumer starts gathering, from its first request
+to its flush, only once no other consumer is still forming a batch.  Two
+consumers therefore never split arrivals into two half-full batches, and
+the next consumer's forming window opens right after the previous flush.
+
 The queue is **priority- and deadline-aware** (the fleet tier's request
 model):
 
@@ -244,6 +250,10 @@ class MicroBatcher:
         # served or drained) or raises — no request can slip into the
         # queue after drain() has emptied it
         self._cond = threading.Condition()
+        #: the forming turn, held by the consumer forming a batch from its
+        #: first request to the formed frames.  Reentrant: a consumer may
+        #: take it before :meth:`get_batch` to wait for it on its own.
+        self.turn = threading.RLock()
         self._next_flush = 0.0  # pace gate: earliest next flush time
         # counters (exact totals, exported by the engine's stats)
         self.n_expired = 0     # requests failed fast on a passed deadline
@@ -441,8 +451,24 @@ class MicroBatcher:
         *every* gathering round, never held until this call returns — a
         consumer blocking with ``timeout=None`` on an idle queue cannot
         leave ``DeadlineExceeded`` futures unresolved past their round.
+
+        A consumer first waits for the forming turn (:attr:`turn`, held
+        by another consumer still gathering or forming); the timeout
+        covers that wait too.  The turn passes on once the batch's frames
+        are formed.
         """
         wait_deadline = None if timeout is None else self._clock() + timeout
+        if not self.turn.acquire(timeout=-1 if wait_deadline is None else
+                                 max(0.0, wait_deadline - self._clock())):
+            return None
+        try:
+            return self._get_batch_turn(wait_deadline)
+        finally:
+            self.turn.release()
+
+    def _get_batch_turn(self, wait_deadline: Optional[float]
+                        ) -> Optional[MicroBatch]:
+        """:meth:`get_batch` for the consumer holding the forming turn."""
         while True:
             expired: List[Request] = []
             with self._cond:

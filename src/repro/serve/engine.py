@@ -40,6 +40,7 @@ drives.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -76,7 +77,24 @@ from repro.obs.trace import (
 from repro.serve.batcher import EngineClosed, MicroBatcher, QueueFull
 
 __all__ = ["AMCServeEngine", "AsyncAMCServeEngine", "ServeStats",
-           "BoundVersion"]
+           "BoundVersion", "default_workers"]
+
+#: Serving threads, and so batches in flight, when the step runs on an
+#: accelerator: one thread gathers, puts and dispatches the next batch
+#: while another waits for the device's work on the current one.
+ACCELERATOR_WORKERS = 2
+
+
+def default_workers(devices) -> int:
+    """Serving threads for a step that runs on ``devices``.
+
+    Two on an accelerator, where the step runs asynchronously on hardware
+    the host does not compute on, so the next batch's host work can
+    overlap the device's; one on the CPU, where the "device" is the
+    host's own cores and a second batch only competes for them.
+    """
+    platforms = {d.platform for d in devices}
+    return 1 if platforms <= {"cpu"} else ACCELERATOR_WORKERS
 
 
 @dataclasses.dataclass
@@ -366,6 +384,12 @@ class AsyncAMCServeEngine:
     than one local device (or an explicit ``mesh``) every batch is fanned
     across the mesh's ``data`` axis via ``shard_map``; bucket sizes are
     forced to multiples of the device count so the split is always even.
+
+    ``workers`` serving threads each run the whole loop for one batch at a
+    time (gather, put, dispatch, fetch, resolve), so up to ``workers``
+    batches are in flight; the batcher lets one form at a time.  ``None``
+    takes :func:`default_workers` of the step's devices: two on an
+    accelerator, one on the CPU.
     """
 
     def __init__(
@@ -378,7 +402,7 @@ class AsyncAMCServeEngine:
         max_batch: Optional[int] = None,   # default 64 (or buckets[-1])
         max_delay_ms: float = 5.0,
         buckets: Optional[Sequence[int]] = None,
-        workers: int = 1,
+        workers: Optional[int] = None,
         max_queue: Optional[int] = None,
         pace_ms: float = 0.0,
         priority_weights=None,
@@ -422,6 +446,10 @@ class AsyncAMCServeEngine:
         self._m_batches = reg.counter(
             "repro_serve_batches_total", "Micro-batches served",
             ("engine", "backend"))
+        self._m_overlapped = reg.counter(
+            "repro_serve_overlapped_batches_total",
+            "Micro-batches dispatched while another batch of the engine "
+            "was dispatched and not yet fetched", ("engine",)).labels(engine=eng)
         self._m_transfers = reg.counter(
             "repro_serve_result_transfers_total",
             "Device-to-host copies of a batch's results (one per batch)",
@@ -559,7 +587,14 @@ class AsyncAMCServeEngine:
         self._t_first_enqueue = float("inf")  # start of the serving window
         self._t_started = time.perf_counter()
         self._busy_s = 0.0  # cumulative worker time spent serving batches
+        # batches dispatched and not yet fetched, under a lock of its own
+        # so that a dispatch never waits on another batch's stats
+        self._in_flight = 0
+        self._flight_lock = threading.Lock()
         self._stop = threading.Event()
+        if workers is None:
+            workers = default_workers(
+                mesh.devices.flat if mesh is not None else jax.devices()[:1])
         self._threads = [
             threading.Thread(target=self._worker, daemon=True,
                              name=f"amc-serve-worker-{i}")
@@ -651,9 +686,25 @@ class AsyncAMCServeEngine:
             ver = self._versions.get(label) if label is not None else None
             return ver if ver is not None else self._versions[self._primary]
 
+    @contextlib.contextmanager
+    def _on_device(self):
+        """A batch from its dispatch to its fetch: counts the batches
+        dispatched while another was still in flight."""
+        with self._flight_lock:
+            overlapped = self._in_flight > 0
+            self._in_flight += 1
+        if overlapped:
+            self._m_overlapped.inc()
+        try:
+            yield
+        finally:
+            with self._flight_lock:
+                self._in_flight -= 1
+
     def _worker(self) -> None:
         # one profiler span per phase of each batch, tiling the loop:
-        # engine.gather (batcher.form inside it), engine.put,
+        # engine.turn (the batcher's forming turn; another serving thread
+        # may hold it), engine.gather (batcher.form inside it), engine.put,
         # engine.dispatch (to the step's asynchronous return), engine.fetch
         # (the wait for the device and the one copy back: a batch's logits
         # and live counters arrive in a single array), engine.counters
@@ -662,10 +713,22 @@ class AsyncAMCServeEngine:
         # after dispatch and a batch's outputs stay bound until the next
         # batch rebinds them: freeing a device array releases the
         # interpreter lock, which right after the futures resolve the
-        # client would take for its whole refill.
+        # client would take for its whole refill.  Each serving thread runs
+        # this loop; what they share (the engine lock's stats, _busy_s,
+        # the in-flight count, the registry, the activity observer) is
+        # locked, and everything of one batch stays on its thread.
         while not self._stop.is_set():
-            with span("engine.gather"):
-                batch = self.batcher.get_batch(timeout=0.1)
+            # the wait for another thread's forming turn has a span of its
+            # own: engine.gather holds this thread's gathering alone
+            with span("engine.turn"):
+                turn = self.batcher.turn.acquire(timeout=0.1)
+            if not turn:
+                continue
+            try:
+                with span("engine.gather"):
+                    batch = self.batcher.get_batch(timeout=0.1)
+            finally:
+                self.batcher.turn.release()
             if batch is None:
                 continue
             t_busy0 = time.perf_counter()
@@ -679,12 +742,13 @@ class AsyncAMCServeEngine:
                 t_step0 = time.perf_counter()
                 with span("engine.put"):
                     x = jnp.asarray(batch.frames)
-                with span("engine.dispatch", bucket=batch.bucket,
-                          n_real=batch.n_real, backend=ver.backend):
-                    out = ver.step(x)
-                    del x   # now, not at the next batch's put
-                with span("engine.fetch", arrays=1):
-                    host = np.asarray(out)
+                with self._on_device():
+                    with span("engine.dispatch", bucket=batch.bucket,
+                              n_real=batch.n_real, backend=ver.backend):
+                        out = ver.step(x)
+                        del x   # now, not at the next batch's put
+                    with span("engine.fetch", arrays=1):
+                        host = np.asarray(out)
                 t_step1 = time.perf_counter()
                 self._m_transfers.inc()
                 self._ready.set()  # first successful jit step: /readyz 200
@@ -1013,9 +1077,10 @@ class AsyncAMCServeEngine:
     def close(self) -> None:
         """Stop the workers; no future is ever left unresolved.
 
-        In-flight batches finish (workers join after their current batch);
-        requests still queued are drained and their futures failed with a
-        ``RuntimeError`` so blocked callers wake instead of hanging.
+        In-flight batches finish (every serving thread joins after the
+        batch it holds); requests still queued are drained and their
+        futures failed with a ``RuntimeError`` so blocked callers wake
+        instead of hanging.
         """
         self._stop.set()
         self.batcher.close()

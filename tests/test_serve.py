@@ -144,6 +144,63 @@ def test_expired_requests_fail_even_while_consumer_keeps_blocking():
     assert not consumer.is_alive()
 
 
+def _run_trickle(n_consumers: int, n_frames: int = 200):
+    """``n_consumers`` threads drain one batcher fed a frame every ~1 ms;
+    returns the batches they formed."""
+    from repro.obs.trace import RequestTrace, TraceLog
+
+    mb = MicroBatcher(FRAME_SHAPE, max_batch=64, max_delay_ms=20)
+    log = TraceLog(capacity=4 * n_frames)
+    batches, lock = [], threading.Lock()
+
+    def consume():
+        while True:
+            batch = mb.get_batch(timeout=0.05)
+            if batch is None:
+                if mb.closed:
+                    return
+                continue
+            with lock:
+                batches.append(batch)
+            for r in batch.requests:
+                r.future.set_result(0)
+
+    threads = [threading.Thread(target=consume, daemon=True)
+               for _ in range(n_consumers)]
+    for th in threads:
+        th.start()
+    frame = _iq(1)[0]
+    for i in range(n_frames):
+        mb.submit(frame, trace=RequestTrace(i, log))
+        time.sleep(0.001)
+    assert mb.drain_barrier(timeout=5.0)
+    mb.close()
+    for th in threads:
+        th.join(timeout=5.0)
+        assert not th.is_alive()
+    assert sum(b.n_real for b in batches) == n_frames
+    return batches
+
+
+def test_two_consumers_form_one_batch_at_a_time():
+    """Under a trickle two consumers take turns: a forming window (first
+    request dequeued -> batch formed) opens only after the previous one
+    closed, so arrivals are never split into two half-full batches."""
+    def window(batch):
+        events = [ev for r in batch.requests for ev in r.trace.events]
+        return (min(ev.t for ev in events if ev.name == "dequeue"),
+                max(ev.t for ev in events if ev.name == "batch-form"))
+
+    one = _run_trickle(1)
+    two = _run_trickle(2)
+    windows = sorted(window(b) for b in two)
+    for (_, prev_end), (start, _) in zip(windows, windows[1:]):
+        assert start >= prev_end
+    mean = {k: np.mean([b.n_real for b in batches])
+            for k, batches in (("one", one), ("two", two))}
+    assert mean["two"] >= 0.75 * mean["one"], mean
+
+
 # ---------------------------------------------------------------------------
 # tail padding: exactly N predictions, no padded-frame leakage into stats
 # ---------------------------------------------------------------------------
@@ -483,6 +540,193 @@ def test_classify_timeout_cancels_queued_futures(setup):
     finally:
         eng.close()
     assert eng.stats.requests == 0
+
+
+# ---------------------------------------------------------------------------
+# two serving threads: two batches in flight
+# ---------------------------------------------------------------------------
+
+def _stub_step(pred, entered=None, hold=None, sleep_s=0.0):
+    """A serving step answering class ``pred`` for every row; it signals
+    ``entered``, then waits for ``hold`` or sleeps, as a device would."""
+    def step(x):
+        if entered is not None:
+            entered.release()
+        if hold is not None:
+            assert hold.wait(timeout=30.0)
+        time.sleep(sleep_s)
+        out = np.zeros((x.shape[0], CFG.n_classes), np.float32)
+        out[:, pred] = 1.0
+        return out
+    return step
+
+
+def test_two_workers_answer_as_one(setup):
+    _, params, masks = setup
+    iq = _iq(300, seed=5)
+    preds = {}
+    for workers in (1, 2):
+        with AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                                 max_batch=8, max_delay_ms=1.0,
+                                 warmup=False, workers=workers,
+                                 name=f"depth-{workers}") as engine:
+            assert engine.n_workers == workers
+            preds[workers] = engine.classify(iq)
+            assert engine.stats.requests == 300
+    np.testing.assert_array_equal(preds[2], preds[1])
+
+
+def test_close_with_both_workers_mid_batch_leaves_no_future_pending(setup):
+    _, params, masks = setup
+    engine = AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                                 max_batch=4, max_delay_ms=1000.0,
+                                 warmup=False, workers=2)
+    entered, hold = threading.Semaphore(0), threading.Event()
+    engine.get_version("default").step = _stub_step(3, entered, hold)
+    futures = [engine.submit(f) for f in _iq(24, seed=9)]
+    try:
+        for _ in range(2):              # both threads inside a step
+            assert entered.acquire(timeout=30.0)
+        closer = threading.Thread(target=engine.close)
+        closer.start()
+        closer.join(timeout=0.2)
+        assert closer.is_alive()        # waits for the batches in flight
+    finally:
+        hold.set()
+    closer.join(timeout=30.0)
+    assert not closer.is_alive()
+    assert all(f.done() for f in futures)
+    served = [f.result() for f in futures if f.exception() is None]
+    assert served == [3] * 8            # the two in-flight batches
+    for f in futures:
+        if f.exception() is not None:
+            assert "closed" in str(f.exception())
+
+
+def test_hot_swap_with_two_in_flight_pins_each_batch(setup):
+    """A swap while one thread's batch is on the device: that batch
+    completes on the version that started it, and the other thread
+    serves the next batch on the new primary meanwhile."""
+    _, params, masks = setup
+    engine = AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                                 max_batch=4, max_delay_ms=1000.0,
+                                 warmup=False, workers=2)
+    entered, hold = threading.Semaphore(0), threading.Event()
+    try:
+        engine.get_version("default").step = _stub_step(0, entered, hold)
+        engine.bind_version("canary", params, masks=masks, warmup=False)
+        engine.get_version("canary").step = _stub_step(1)
+        first = [engine.submit(f) for f in _iq(4, seed=1)]
+        assert entered.acquire(timeout=30.0)
+        assert engine.swap_to("canary") == "default"
+        second = [engine.submit(f) for f in _iq(4, seed=2)]
+        assert [f.result(timeout=30.0) for f in second] == [1] * 4
+        assert not any(f.done() for f in first)
+        hold.set()
+        assert [f.result(timeout=30.0) for f in first] == [0] * 4
+        stats = engine.version_stats()
+        assert stats["default"].requests == 4
+        assert stats["canary"].requests == 4
+    finally:
+        hold.set()
+        engine.close()
+
+
+def test_overlapped_batches_are_counted(setup):
+    from repro.obs.metrics import default_registry
+
+    _, params, masks = setup
+    seen = {}
+    for workers in (1, 2):
+        name = f"overlap-{workers}"
+        with AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                                 max_batch=4, max_delay_ms=1000.0,
+                                 warmup=False, workers=workers,
+                                 name=name) as engine:
+            engine.get_version("default").step = _stub_step(
+                2, sleep_s=0.03)
+            assert list(engine.classify(_iq(32, seed=3))) == [2] * 32
+            batches = engine.stats.batches
+        seen[workers] = (default_registry().value(
+            "repro_serve_overlapped_batches_total", engine=name), batches)
+    assert seen[1] == (0.0, 8)
+    overlapped, batches = seen[2]
+    assert batches == 8 and batches // 2 <= overlapped < batches
+
+
+def test_more_workers_than_cores_lose_no_update(setup):
+    """Serving threads outnumbering the cores, switching every 10 µs: the
+    shared counts (engine stats, registry, in-flight) lose no update and
+    every future resolves."""
+    import os
+    import sys
+
+    from repro.obs.metrics import default_registry
+
+    _, params, masks = setup
+    workers = min(os.cpu_count() or 1, 30) + 2
+    n = 600
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                                 max_batch=4, max_delay_ms=1.0,
+                                 warmup=False, workers=workers,
+                                 name="stress") as engine:
+            engine.get_version("default").step = _stub_step(
+                1, sleep_s=0.001)
+            futures = [engine.submit(f) for f in _iq(n, seed=11)]
+            assert [f.result(timeout=60) for f in futures] == [1] * n
+            stats = engine.stats
+            assert stats.requests == n
+            assert engine._in_flight == 0
+        reg = default_registry()
+        batches = reg.value("repro_serve_batches_total", engine="stress",
+                            backend="dense")
+        assert batches == stats.batches >= n // 4
+        assert reg.value("repro_serve_requests_total", engine="stress") == n
+        assert reg.value("repro_serve_overlapped_batches_total",
+                         engine="stress") <= batches
+    finally:
+        sys.setswitchinterval(interval)
+
+
+def test_two_workers_stay_busy_at_saturation(setup):
+    """Windowed utilization as the fleet autoscaler reads it (busy seconds
+    over elapsed × threads) stays above its default scale-up threshold
+    when two serving threads drain a full queue: the forming turn does not
+    make a saturated replica look idle."""
+    import inspect
+
+    from repro.fleet.autoscaler import Autoscaler
+
+    high = inspect.signature(Autoscaler).parameters["high_utilization"].default
+    _, params, masks = setup
+    with AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                             max_batch=4, max_delay_ms=1000.0,
+                             warmup=False, workers=2,
+                             name="saturated-2") as engine:
+        engine.get_version("default").step = _stub_step(2, sleep_s=0.02)
+        futures = [engine.submit(f) for f in _iq(240, seed=7)]
+        futures[15].result(timeout=60)   # both threads serving a full queue
+        t0, busy0 = time.perf_counter(), engine.busy_s
+        futures[-16].result(timeout=60)  # the queue still holds batches
+        t1, busy1 = time.perf_counter(), engine.busy_s
+        assert [f.result(timeout=60) for f in futures] == [2] * 240
+    assert (busy1 - busy0) / ((t1 - t0) * 2) > high
+
+
+def test_default_depth_is_one_on_cpu_two_on_an_accelerator(setup):
+    from types import SimpleNamespace
+
+    from repro.serve.engine import default_workers
+
+    _, params, masks = setup
+    assert default_workers(jax.devices()) == 1
+    assert default_workers([SimpleNamespace(platform="tpu")]) == 2
+    with AsyncAMCServeEngine(params, CFG, masks=masks, backend="dense",
+                             warmup=False) as engine:
+        assert engine.n_workers == 1
 
 
 # ---------------------------------------------------------------------------
